@@ -14,19 +14,20 @@ if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", "")
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
 
 from repro.ckpt import latest_step, restore_checkpoint, save_checkpoint
 from repro.configs import get_config
 from repro.ft import snapshot_resharded
 from repro.io import IOPolicy, open_store
-from repro.launch.mesh import make_mesh_compat
 from repro.models import make_model
 from repro.models.spec import param_shardings
 from repro.sharding.rules import ShardingRules, TRAIN_RULES
 
 
 def mesh_of(data: int, model: int) -> jax.sharding.Mesh:
-    return make_mesh_compat((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def main() -> None:

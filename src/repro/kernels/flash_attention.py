@@ -25,9 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 spells CompilerParams TPUCompilerParams.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
@@ -138,7 +135,7 @@ def flash_attention(
             pltpu.VMEM((block_q, 1), jnp.float32),   # running normalizer
             pltpu.VMEM((block_q, d), jnp.float32),   # output accumulator
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

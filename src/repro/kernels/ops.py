@@ -1,7 +1,8 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-`interpret` defaults to auto: Pallas lowers natively on TPU and falls back
-to interpret mode elsewhere (CPU CI), so call sites never branch.
+Kernels lower natively for the TPU. `interpret=True` runs them in the
+Pallas interpreter instead, which is how the CPU tests check them; the
+caller says so explicitly, so a run never drops off the device unseen.
 """
 
 from __future__ import annotations
@@ -14,16 +15,11 @@ from repro.kernels import flash_attention as _fa
 from repro.kernels import ssd_scan as _ssd
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
                                              "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool | None = None):
+                    block_k: int = 128, interpret: bool = False):
     """q: (B, Hq, Sq, D); k/v: (B, Hkv, Sk, D) -> (B, Hq, Sq, D)."""
-    interpret = _default_interpret() if interpret is None else interpret
     return _fa.flash_attention(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k,
         interpret=interpret,
@@ -32,9 +28,8 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_scan(x, dt_a, b_proj, c_proj, *, chunk: int = 256,
-             initial_state=None, interpret: bool | None = None):
+             initial_state=None, interpret: bool = False):
     """Fused Mamba-2 SSD scan. x: (B,S,H,P) dt-scaled; returns (y, state)."""
-    interpret = _default_interpret() if interpret is None else interpret
     return _ssd.ssd_scan(
         x, dt_a, b_proj, c_proj, chunk=chunk,
         initial_state=initial_state, interpret=interpret,

@@ -1,9 +1,10 @@
 """Mamba-2 SSD chunked scan as a fused Pallas TPU kernel.
 
-Grid (batch, heads, chunks) with the chunk dimension sequential
-("arbitrary"): the inter-chunk SSM state (head_dim x state) is carried in
-fp32 VMEM scratch across grid steps — the whole recurrence runs in one
-kernel launch. Intra-chunk work is dense MXU matmuls:
+Grid (batch, heads, chunks) over head-major operands, with the chunk
+dimension sequential ("arbitrary"): the inter-chunk SSM state
+(head_dim x state) is carried in fp32 VMEM scratch across grid steps —
+the whole recurrence runs in one kernel launch. Intra-chunk work is
+dense MXU matmuls:
 
     acs    = cumsum(dt_a)                     (via lower-tri ones matmul)
     L      = exp(acs_i - acs_j) . tril        (1-semiseparable decay)
@@ -26,19 +27,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 spells CompilerParams TPUCompilerParams.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
-NEG_INF = -1e30
-
 
 def _ssd_kernel(
-    x_ref,        # (1, Q, 1, P)
-    a_ref,        # (1, Q, 1)
-    b_ref,        # (1, Q, 1, N)
-    c_ref,        # (1, Q, 1, N)
+    x_ref,        # (1, 1, Q, P)
+    a_ref,        # (1, 1, Q, 1)
+    b_ref,        # (1, 1, Q, N)
+    c_ref,        # (1, 1, Q, N)
     init_ref,     # (1, 1, P, N)
-    y_ref,        # (1, Q, 1, P) out
+    y_ref,        # (1, 1, Q, P) out
     final_ref,    # (1, 1, P, N) out
     h_ref,        # VMEM scratch (P, N) fp32
     *,
@@ -50,21 +46,25 @@ def _ssd_kernel(
     def _init():
         h_ref[...] = init_ref[0, 0].astype(jnp.float32)
 
-    x = x_ref[0, :, 0, :].astype(jnp.float32)        # (Q, P)
-    a = a_ref[0, :, 0].astype(jnp.float32)           # (Q,)
-    bmat = b_ref[0, :, 0, :].astype(jnp.float32)     # (Q, N)
-    cmat = c_ref[0, :, 0, :].astype(jnp.float32)     # (Q, N)
+    x = x_ref[0, 0].astype(jnp.float32)              # (Q, P)
+    a = a_ref[0, 0].astype(jnp.float32)              # (Q, 1)
+    bmat = b_ref[0, 0].astype(jnp.float32)           # (Q, N)
+    cmat = c_ref[0, 0].astype(jnp.float32)           # (Q, N)
     q = x.shape[0]
 
-    # Inclusive cumsum via lower-triangular ones matmul (MXU-friendly).
+    # Inclusive cumsum via lower-triangular ones matmul (MXU-friendly),
+    # as a column and as a row so no in-kernel transpose is needed.
     rows = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
     tril_inc = (cols <= rows).astype(jnp.float32)    # includes diagonal
-    acs = jax.lax.dot(tril_inc, a[:, None],
-                      preferred_element_type=jnp.float32)[:, 0]  # (Q,)
+    acs = jax.lax.dot(tril_inc, a, preferred_element_type=jnp.float32)  # (Q, 1)
+    acs_row = jax.lax.dot_general(
+        a, tril_inc, (((0,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )                                                              # (1, Q)
 
     # Intra-chunk decay matrix.
-    seg = acs[:, None] - acs[None, :]
+    seg = acs - acs_row
     l_mat = jnp.where(cols <= rows, jnp.exp(seg), 0.0)            # (Q, Q)
 
     cb = jax.lax.dot_general(
@@ -77,17 +77,17 @@ def _ssd_kernel(
     y_off = jax.lax.dot_general(
         cmat, h_prev, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
-    ) * jnp.exp(acs)[:, None]                                      # (Q, P)
+    ) * jnp.exp(acs)                                               # (Q, P)
 
-    chunk_decay = jnp.exp(acs[-1])
-    decay_states = jnp.exp(acs[-1] - acs)                          # (Q,)
+    acs_last = jnp.sum(a, axis=0, keepdims=True)                   # (1, 1)
+    decay_states = jnp.exp(acs_last - acs)                         # (Q, 1)
     state_update = jax.lax.dot_general(
-        x, bmat * decay_states[:, None], (((0,), (0,)), ((), ())),
+        x, bmat * decay_states, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )                                                              # (P, N)
-    h_ref[...] = h_prev * chunk_decay + state_update
+    h_ref[...] = h_prev * jnp.exp(acs_last) + state_update
 
-    y_ref[0, :, 0, :] = (y_diag + y_off).astype(y_ref.dtype)
+    y_ref[0, 0] = (y_diag + y_off).astype(y_ref.dtype)
 
     @pl.when(ic == num_chunks - 1)
     def _finish():
@@ -114,32 +114,40 @@ def ssd_scan(
     if initial_state is None:
         initial_state = jnp.zeros((bsz, h, p, n), jnp.float32)
 
+    # Head-major inside the call: every block's last two dims are then
+    # (chunk, P), (chunk, 1) or (chunk, N), which Mosaic tiles; a
+    # (1, chunk, 1, P) block over (B, S, H, P) puts a 1 against H.
+    x_hm = x.transpose(0, 2, 1, 3)                   # (B, H, S, P)
+    a_hm = dt_a.transpose(0, 2, 1)[..., None]        # (B, H, S, 1)
+    b_hm = b_proj.transpose(0, 2, 1, 3)              # (B, G, S, N)
+    c_hm = c_proj.transpose(0, 2, 1, 3)
+
     kernel = functools.partial(_ssd_kernel, num_chunks=nc)
     grid = (bsz, h, nc)
     y, final = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, chunk, 1, p), lambda b, hh, c: (b, c, hh, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda b, hh, c: (b, c, hh)),
-            pl.BlockSpec((1, chunk, 1, n),
-                         lambda b, hh, c, rep=rep: (b, c, hh // rep, 0)),
-            pl.BlockSpec((1, chunk, 1, n),
-                         lambda b, hh, c, rep=rep: (b, c, hh // rep, 0)),
+            pl.BlockSpec((1, 1, chunk, p), lambda b, hh, c: (b, hh, c, 0)),
+            pl.BlockSpec((1, 1, chunk, 1), lambda b, hh, c: (b, hh, c, 0)),
+            pl.BlockSpec((1, 1, chunk, n),
+                         lambda b, hh, c, rep=rep: (b, hh // rep, c, 0)),
+            pl.BlockSpec((1, 1, chunk, n),
+                         lambda b, hh, c, rep=rep: (b, hh // rep, c, 0)),
             pl.BlockSpec((1, 1, p, n), lambda b, hh, c: (b, hh, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, chunk, 1, p), lambda b, hh, c: (b, c, hh, 0)),
+            pl.BlockSpec((1, 1, chunk, p), lambda b, hh, c: (b, hh, c, 0)),
             pl.BlockSpec((1, 1, p, n), lambda b, hh, c: (b, hh, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bsz, s, h, p), x.dtype),
+            jax.ShapeDtypeStruct((bsz, h, s, p), x.dtype),
             jax.ShapeDtypeStruct((bsz, h, p, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(x, dt_a, b_proj, c_proj, initial_state)
-    return y, final
+    )(x_hm, a_hm, b_hm, c_hm, initial_state)
+    return y.transpose(0, 2, 1, 3), final

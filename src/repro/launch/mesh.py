@@ -7,32 +7,14 @@ touches jax device state — smoke tests must keep seeing 1 CPU device.
 from __future__ import annotations
 
 import jax
-
-
-def make_mesh_compat(shape, axes) -> jax.sharding.Mesh:
-    """`jax.make_mesh` with explicit Auto axis types where the installed
-    jax supports them (axis_types landed after 0.4.x; Auto is the default
-    semantic either way)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    try:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    except TypeError:
-        return jax.make_mesh(shape, axes)
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
-
-
-def make_debug_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
-    """Small mesh over however many devices exist (CPU tests)."""
-    return make_mesh_compat((data, model), ("data", "model"))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def mesh_host_shard() -> tuple[int, int]:

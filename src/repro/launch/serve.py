@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import argparse
 import time
+from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
@@ -19,13 +20,26 @@ import numpy as np
 from repro.ckpt.manager import restore_checkpoint, save_checkpoint
 from repro.configs import get_config
 from repro.io import IOPolicy, open_store
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import make_model
 from repro.utils import get_logger
 
 log = get_logger("launch.serve")
 
 
-def main() -> None:
+@dataclass
+class ServeRun:
+    """What one `main` call did, for callers that drive it in-process."""
+
+    ids: np.ndarray          # (batch, gen) generated token ids
+    logits: np.ndarray       # (batch, vocab) logits of the last decode step
+    restore_s: float         # cold-start weight restore from the store
+    prefill_s: float | None  # first prefill including its compile
+    decode_s: float          # all decode steps, the first one's compile too
+
+
+def main(argv: list[str] | None = None) -> ServeRun:
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="smollm-135m")
     ap.add_argument("--reduced", action="store_true", default=True)
@@ -48,7 +62,7 @@ def main() -> None:
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--quant", choices=["int8"], default=None,
                     help="weight-only int8 serving (TP-only layout)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -60,7 +74,7 @@ def main() -> None:
     params = model.init(jax.random.key(0))
     save_checkpoint(store, "weights", 0, params,
                     policy=IOPolicy(write_depth=4))
-    t0 = time.time()
+    t0 = time.perf_counter()
     params, _ = restore_checkpoint(
         store, "weights", params,
         policy=IOPolicy(engine=args.restore_mode, depth=2,
@@ -69,7 +83,9 @@ def main() -> None:
                         eviction_interval_s=0.2),
         cache_dir=args.cache_dir,
     )
-    print(f"weight restore ({args.restore_mode}): {time.time() - t0:.2f}s"
+    jax.block_until_ready(params)
+    restore_s = time.perf_counter() - t0
+    print(f"weight restore ({args.restore_mode}): {restore_s:.2f}s"
           + (f" [cache: {args.cache_dir}]" if args.cache_dir else ""))
     if args.quant == "int8":
         from repro.models.quant import quantize_params
@@ -99,6 +115,7 @@ def main() -> None:
     # Decode needs cache headroom for generated tokens.
     prompt_tokens = 8 if cfg.is_encdec else s
     max_len = prompt_tokens + args.gen
+    prefill_s = None
     if cfg.is_encdec:
         from repro.models import encdec as ED
 
@@ -120,15 +137,12 @@ def main() -> None:
     else:
         from repro.models import lm as LM
 
-        caches = LM.make_stack_cache(cfg, b, max_len)
-        t0 = time.time()
-        h, caches, _ = LM.lm_hidden(
-            params, cfg, batch["inputs"], caches=caches, update_cache=True,
-            q_chunk=min(512, s),
-        )
-        logits = LM.logits_from_hidden(params, cfg, h[:, -1:, :])[:, 0]
-        print(f"prefill: {time.time() - t0:.2f}s "
-              f"({b * s / (time.time() - t0):.0f} tok/s)")
+        t0 = time.perf_counter()
+        logits, caches = LM.lm_prefill(params, cfg, batch["inputs"],
+                                       max_len=max_len, q_chunk=min(512, s))
+        jax.block_until_ready(logits)
+        prefill_s = time.perf_counter() - t0
+        print(f"prefill: {prefill_s:.2f}s ({b * s / prefill_s:.0f} tok/s)")
 
     # --- decode loop -----------------------------------------------------------
     decode = jax.jit(
@@ -137,7 +151,7 @@ def main() -> None:
     key = jax.random.key(1)
     tok = jnp.argmax(logits[:, : cfg.vocab_size], axis=-1)[:, None]
     generated = [tok]
-    t0 = time.time()
+    t0 = time.perf_counter()
     for i in range(args.gen - 1):
         pos = prompt_tokens + i
         if cfg.embed_inputs and not cfg.is_encdec:
@@ -155,11 +169,13 @@ def main() -> None:
             tok = jnp.argmax(logits, axis=-1)[:, None]
         generated.append(tok)
     jax.block_until_ready(tok)
-    dt = time.time() - t0
-    out = jnp.concatenate(generated, axis=1)
+    dt = time.perf_counter() - t0
+    out = np.asarray(jnp.concatenate(generated, axis=1))
     print(f"decoded {args.gen} tokens x {b} seqs in {dt:.2f}s "
           f"({b * args.gen / dt:.1f} tok/s)")
-    print("sample token ids:", np.asarray(out[0, :16]))
+    print("sample token ids:", out[0, :16])
+    return ServeRun(ids=out, logits=np.asarray(logits[:, : cfg.vocab_size]),
+                    restore_s=restore_s, prefill_s=prefill_s, decode_s=dt)
 
 
 if __name__ == "__main__":

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import argparse
 import time
+from dataclasses import dataclass
 
 import jax
 import numpy as np
@@ -23,12 +24,14 @@ from repro.configs import get_config
 from repro.data import DataCursor, LoaderConfig, PrefetchingDataLoader, synth_token_shard
 from repro.data.loader import DeviceFeeder
 from repro.io import IOPolicy, open_store
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import mesh_host_shard
 from repro.models import make_model
 from repro.store import LinkModel, MemTier, SimS3Store
 from repro.train import (
     AdamWConfig,
     StepConfig,
+    TrainState,
     build_train_step,
     init_train_state,
 )
@@ -50,7 +53,20 @@ def build_data_store(n_shards: int, tokens_per_shard: int, vocab: int,
     return store
 
 
-def main() -> None:
+@dataclass
+class TrainRun:
+    """What one `main` call did, for callers that drive it in-process."""
+
+    state: TrainState            # the live state after the last step
+    start_step: int              # 0, or the step a checkpoint resumed from
+    losses: dict[int, float]     # logged step -> loss
+    restore_s: float | None      # checkpoint restore, None when not resumed
+    first_step_s: float | None   # first step including its compile
+    wall_s: float
+
+
+def main(argv: list[str] | None = None) -> TrainRun:
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="smollm-135m")
     ap.add_argument("--reduced", action="store_true", default=True)
@@ -74,14 +90,16 @@ def main() -> None:
     ap.add_argument("--s3-latency", type=float, default=0.01)
     ap.add_argument("--s3-bandwidth", type=float, default=50e6)
     ap.add_argument("--log-every", type=int, default=10)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
     model = make_model(cfg)
-    log.warning("arch=%s params=%.2fM devices=%d", cfg.name,
-                model.param_count() / 1e6, jax.device_count())
+    dev = jax.devices()[0]
+    log.warning("arch=%s params=%.2fM devices=%d x %s (%s)", cfg.name,
+                model.param_count() / 1e6, jax.device_count(),
+                dev.device_kind, dev.platform)
 
     # --- data ---------------------------------------------------------------
     data_store = build_data_store(
@@ -113,9 +131,10 @@ def main() -> None:
                          donate_argnums=(0,))
 
     state = init_train_state(model, jax.random.key(0))
-    start_step, cursor = 0, DataCursor()
+    start_step, cursor, restore_s = 0, DataCursor(), None
     resume = latest_step(ckpt_store, "ckpt")
     if resume is not None:
+        t0 = time.perf_counter()
         # Multi-process mesh: each host prefetch-warms only its
         # rendezvous-owned slice of the checkpoint stream (a peer://
         # ckpt store serves the rest over the LAN). Single process:
@@ -125,6 +144,8 @@ def main() -> None:
             ckpt_store, "ckpt", state,
             shard=(host_id, num_hosts) if num_hosts > 1 else None,
         )
+        jax.block_until_ready(state)
+        restore_s = time.perf_counter() - t0
         start_step = manifest["step"]
         cursor = DataCursor.from_dict(manifest["extra"].get("cursor", cursor.to_dict()))
         log.warning("resumed from step %d", start_step)
@@ -155,27 +176,39 @@ def main() -> None:
     it = iter(feeder)
     t0 = time.time()
     tokens = 0
+    losses: dict[int, float] = {}
+    first_step_s = None
     for step in range(start_step, args.steps):
         inputs, labels = next(it)
+        t_step = time.perf_counter()
         state, metrics = train_step(state, {"inputs": inputs, "labels": labels})
+        if first_step_s is None:
+            jax.block_until_ready(metrics["loss"])
+            first_step_s = time.perf_counter() - t_step
         tokens += inputs.shape[0] * inputs.shape[1]
         if (step + 1) % args.log_every == 0 or step + 1 == args.steps:
-            jax.block_until_ready(metrics["loss"])
+            losses[step + 1] = float(metrics["loss"])
             dt = time.time() - t0
             print(
-                f"step={step + 1} loss={float(metrics['loss']):.4f} "
+                f"step={step + 1} loss={losses[step + 1]:.4f} "
                 f"grad_norm={float(metrics['grad_norm']):.3f} "
                 f"lr={float(metrics['lr']):.2e} tok/s={tokens / dt:.0f}"
             )
         ckpt.maybe_save(step + 1, state,
                         extra={"cursor": loader.cursor.to_dict()})
-    ckpt.maybe_save(args.steps, state, force=True,
-                    extra={"cursor": loader.cursor.to_dict()})
+    if args.steps > start_step and args.steps % args.ckpt_interval:
+        # The final step, unless the loop has just saved it.
+        ckpt.maybe_save(args.steps, state, force=True,
+                        extra={"cursor": loader.cursor.to_dict()})
     ckpt.wait()
+    feeder.close()
     loader.close()
+    wall_s = time.time() - t0
     print("loader fs stats:", loader.fs_stats().snapshot())
-    print(f"done: {args.steps} steps, {tokens} tokens, "
-          f"{time.time() - t0:.1f}s wall")
+    print(f"done: {args.steps} steps, {tokens} tokens, {wall_s:.1f}s wall")
+    return TrainRun(state=state, start_step=start_step, losses=losses,
+                    restore_s=restore_s, first_step_s=first_step_s,
+                    wall_s=wall_s)
 
 
 if __name__ == "__main__":

@@ -1,12 +1,6 @@
 """Shared test setup.
 
-Installs a deterministic fallback for the small `hypothesis` subset the
-suite uses (``given`` / ``settings`` / ``strategies.integers|floats|lists|
-sampled_from``) when the real package is not importable, so the tier-1
-suite runs in hermetic containers with no package installs. With real
-hypothesis present this module is a no-op.
-
-Also provides the opt-in ``traced_locks`` fixture: it swaps
+Provides the opt-in ``traced_locks`` fixture: it swaps
 ``threading.Lock/RLock/Condition`` for recording wrappers so a test's
 *actual* lock acquisition order is captured, then (teardown) asserts
 every observed nesting is consistent with the static lock-order graph
@@ -18,100 +12,18 @@ suites (test_cache_tiers, test_peer).
 
 from __future__ import annotations
 
-import functools
-import inspect
 import os
-import random
 import sys
 import threading
-import types
 
 import pytest
 
-
-def _install_hypothesis_fallback() -> None:
-    try:
-        import hypothesis  # noqa: F401
-
-        return
-    except ImportError:
-        pass
-
-    class _Strategy:
-        def __init__(self, draw):
-            self.draw = draw  # draw(rng) -> value
-
-    def integers(min_value: int, max_value: int) -> _Strategy:
-        return _Strategy(lambda rng: rng.randint(min_value, max_value))
-
-    def floats(min_value: float, max_value: float) -> _Strategy:
-        return _Strategy(lambda rng: rng.uniform(min_value, max_value))
-
-    def sampled_from(options) -> _Strategy:
-        options = list(options)
-        return _Strategy(lambda rng: options[rng.randrange(len(options))])
-
-    def lists(elements: _Strategy, min_size: int = 0,
-              max_size: int | None = None) -> _Strategy:
-        hi = max_size if max_size is not None else min_size + 10
-
-        def draw(rng):
-            return [elements.draw(rng) for _ in range(rng.randint(min_size, hi))]
-
-        return _Strategy(draw)
-
-    def settings(**kw):
-        def deco(fn):
-            fn._fallback_settings = dict(kw)
-            return fn
-
-        return deco
-
-    def given(**strategy_kw):
-        def deco(fn):
-            @functools.wraps(fn)
-            def wrapper(*args, **kwargs):
-                cfg = getattr(wrapper, "_fallback_settings", None) or getattr(
-                    fn, "_fallback_settings", {}
-                )
-                n = int(cfg.get("max_examples", 25))
-                # Seeded per test so example sequences are reproducible.
-                rng = random.Random(f"{fn.__module__}.{fn.__qualname__}")
-                for _ in range(n):
-                    drawn = {k: s.draw(rng) for k, s in strategy_kw.items()}
-                    fn(*args, **kwargs, **drawn)
-
-            # Hide the drawn parameters from pytest's fixture resolution.
-            sig = inspect.signature(fn)
-            wrapper.__signature__ = sig.replace(parameters=[
-                p for name, p in sig.parameters.items()
-                if name not in strategy_kw
-            ])
-            wrapper.hypothesis_fallback = True
-            return wrapper
-
-        return deco
-
-    mod = types.ModuleType("hypothesis")
-    st = types.ModuleType("hypothesis.strategies")
-    st.integers = integers
-    st.floats = floats
-    st.lists = lists
-    st.sampled_from = sampled_from
-    mod.given = given
-    mod.settings = settings
-    mod.strategies = st
-    sys.modules["hypothesis"] = mod
-    sys.modules["hypothesis.strategies"] = st
-
-
-_install_hypothesis_fallback()
 
 # Imported at collection time, before any fixture patches threading's
 # constructor names — repro.sched captures the real ones at import, and
 # both this file's traced locks and the interleaving explorer's
 # cooperative locks go through the same patch mechanism.
-from repro.sched import patch_threading_ctors  # noqa: E402
+from repro.sched import patch_threading_ctors
 
 
 # ---------------------------------------------------------------------------

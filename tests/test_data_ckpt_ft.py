@@ -332,9 +332,9 @@ class TestElastic:
         store = make_store({})
         state = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}
         save_checkpoint(store, "ckpt", 1, state)
-        from repro.launch.mesh import make_mesh_compat
+        from jax.sharding import AxisType
 
-        mesh = make_mesh_compat((1,), ("data",))
+        mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
         template = {
             "w": jax.ShapeDtypeStruct(
                 (8, 8), jnp.float32,
