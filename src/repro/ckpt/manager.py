@@ -27,6 +27,12 @@ against. The legacy `mode=` string kwarg still works and warns.
 
 Elastic: the restore template's shardings may come from a different mesh
 than save time; `device_put` reshards each leaf onto the new topology.
+
+Each restore is one `restore` span (`repro.utils.trace`) whose children
+split it into the serial metadata requests (`restore.meta`), the facade's
+set-up (`restore.open`), and per leaf the stream's `read` of its bytes
+(`restore.read`), the digest check (`restore.verify`) and the decode and
+`device_put` (`restore.put`), then `restore.close`.
 """
 
 from __future__ import annotations
@@ -47,7 +53,7 @@ from repro.io.integrity import block_digest, check_block
 from repro.io.retry import Retrier, RetryPolicy
 from repro.store.base import ObjectMeta, ObjectStore
 from repro.store.tiers import CacheTier
-from repro.utils import get_logger
+from repro.utils import get_logger, trace
 
 log = get_logger("ckpt")
 
@@ -251,92 +257,142 @@ def restore_checkpoint(
     everything else moves over the LAN. Without a peer store the shard
     warm pass is still correct, just not shared.
     """
-    store = open_store(store)
-    warm_cache = cache_dir is not None and tiers is None
-    if warm_cache:
-        from repro.store.tiers import DirTier
-
-        cap = cache_capacity
-        if cap is None:
-            bs = policy.blocksize if policy is not None else blocksize
-            cap = max(4 * bs, 256 << 20)
-        tiers = [DirTier(cap, root=cache_dir, name="ckpt.cache")]
-    # Everything past tier construction runs under the finally that
-    # releases the cache root's advisory lock — a missing manifest or a
-    # failed metadata call must not leak the lock in a long-lived process
-    # (the retry's DirTier would silently become a non-owner).
-    try:
-        if mode is not None:
-            warnings.warn(
-                "restore_checkpoint(mode=...) is deprecated; pass "
-                "policy=IOPolicy(engine=...) instead",
-                DeprecationWarning,
-                stacklevel=2,
-            )
-        if policy is None:
-            policy = IOPolicy(
-                engine=mode or "rolling",
-                blocksize=blocksize,
-                depth=prefetch_depth,
-                eviction_interval_s=0.2,
-            )
-        if policy.io_class == "default":
-            # Restore streams are the checkpoint workload class (top-tier
-            # HSM admission); an explicit io_class — e.g. "serve" from
-            # `ServeEngine.from_store` — wins.
-            policy = policy.replace(io_class="ckpt")
-        if (warm_cache or shard is not None) and not policy.keep_cached:
-            # Sharded restore serves warmed blocks to siblings: they must
-            # outlive their own consumption.
-            policy = policy.replace(keep_cached=True)
-        if step is None:
-            step = latest_step(store, prefix)
-            if step is None:
-                raise FileNotFoundError(f"no checkpoint under {prefix!r}")
-        manifest = _load_manifest(store, prefix, step)
-        t_leaves, treedef = _flatten(template)
-        entries = manifest["leaves"]
-        if len(entries) != len(t_leaves):
-            raise ValueError(
-                f"template has {len(t_leaves)} leaves, checkpoint {len(entries)}"
-            )
-
-        files = [
-            ObjectMeta(e["key"], _with_retries(lambda k=e["key"]: store.size(k)))
-            for e in entries
-        ]
-        out = []
-        with PrefetchFS(store, policy=policy, tiers=tiers) as fs:
-            if shard is not None:
-                _warm_shard(fs, files, policy, shard)
-            stream = fs.open_many(files)
-            read = getattr(stream, "readview", stream.read)
-            for meta, entry, tmpl in zip(files, entries, t_leaves):
-                # readview: a leaf inside one cached block decodes zero-copy
-                # (np.frombuffer over the block buffer's memoryview).
-                raw = read(meta.size)
-                if policy.verify != "off":
-                    # End-to-end: the digest minted over the serialized
-                    # leaf at save time must match the bytes about to
-                    # become model state — whatever path they took
-                    # (store, cache tiers, peers). Manifests predating
-                    # digests verify nothing (entry.get -> None).
-                    check_block(raw, entry.get("digest"),
-                                what=f"checkpoint leaf {entry['key']}")
-                arr = np.frombuffer(
-                    raw, dtype=_dtype_from_str(entry["dtype"])
-                ).reshape(entry["shape"])
-                sharding = getattr(tmpl, "sharding", None)
-                # device_put overlaps with the prefetch of subsequent leaves.
-                out.append(jax.device_put(arr, sharding))
-    finally:
+    with trace.span("restore"):
+        trace.count("restore.count")
+        store = open_store(store)
+        warm_cache = cache_dir is not None and tiers is None
         if warm_cache:
-            # Release the lock; blocks stay on disk for the next —
-            # possibly warm — restore.
-            for t in tiers:
-                with contextlib.suppress(Exception):
-                    t.close()
-    return jax.tree_util.tree_unflatten(treedef, out), manifest
+            from repro.store.tiers import DirTier
+
+            cap = cache_capacity
+            if cap is None:
+                bs = policy.blocksize if policy is not None else blocksize
+                cap = max(4 * bs, 256 << 20)
+            tiers = [DirTier(cap, root=cache_dir, name="ckpt.cache")]
+        # Everything past tier construction runs under the finally that
+        # releases the cache root's advisory lock — a missing manifest or a
+        # failed metadata call must not leak the lock in a long-lived process
+        # (the retry's DirTier would silently become a non-owner).
+        try:
+            if mode is not None:
+                warnings.warn(
+                    "restore_checkpoint(mode=...) is deprecated; pass "
+                    "policy=IOPolicy(engine=...) instead",
+                    DeprecationWarning,
+                    stacklevel=2,
+                )
+            if policy is None:
+                policy = IOPolicy(
+                    engine=mode or "rolling",
+                    blocksize=blocksize,
+                    depth=prefetch_depth,
+                    eviction_interval_s=0.2,
+                )
+            if policy.io_class == "default":
+                # Restore streams are the checkpoint workload class (top-tier
+                # HSM admission); an explicit io_class — e.g. "serve" from
+                # `ServeEngine.from_store` — wins.
+                policy = policy.replace(io_class="ckpt")
+            if (warm_cache or shard is not None) and not policy.keep_cached:
+                # Sharded restore serves warmed blocks to siblings: they must
+                # outlive their own consumption.
+                policy = policy.replace(keep_cached=True)
+            with trace.span("restore.meta"):
+                manifest, files = _restore_meta(store, prefix, step)
+            t_leaves, treedef = _flatten(template)
+            entries = manifest["leaves"]
+            if len(entries) != len(t_leaves):
+                raise ValueError(
+                    f"template has {len(t_leaves)} leaves, checkpoint {len(entries)}"
+                )
+            fs = None
+            try:
+                with trace.span("restore.open"):
+                    fs = PrefetchFS(store, policy=policy, tiers=tiers)
+                    if shard is not None:
+                        with trace.span("restore.warm"):
+                            _warm_shard(fs, files, policy, shard)
+                    stream = fs.open_many(files)
+                out = _read_leaves(stream, files, entries, t_leaves, policy)
+                totals = fs.stats().totals
+                for name in ("store_requests", "bytes_fetched", "reader_wait_s"):
+                    trace.count(f"restore.{name}", totals.get(name, 0))
+            finally:
+                if fs is not None:
+                    with trace.span("restore.close"):
+                        fs.close()
+        finally:
+            if warm_cache:
+                # Release the lock; blocks stay on disk for the next —
+                # possibly warm — restore.
+                for t in tiers:
+                    with contextlib.suppress(Exception):
+                        t.close()
+        return jax.tree_util.tree_unflatten(treedef, out), manifest
+
+
+def _restore_meta(store: ObjectStore, prefix: str,
+                  step: int | None) -> tuple[dict, list[ObjectMeta]]:
+    """The restore's serial metadata requests: the LIST that finds the latest
+    step (when `step` is None), the manifest GET and one size per leaf."""
+    if step is None:
+        trace.count("restore.meta_requests")
+        step = latest_step(store, prefix)
+        if step is None:
+            raise FileNotFoundError(f"no checkpoint under {prefix!r}")
+    trace.count("restore.meta_requests")
+    manifest = _load_manifest(store, prefix, step)
+    files = []
+    for e in manifest["leaves"]:
+        trace.count("restore.meta_requests")
+        files.append(ObjectMeta(e["key"], _with_retries(lambda k=e["key"]: store.size(k))))
+    return manifest, files
+
+
+def _read_leaves(stream, files: list[ObjectMeta], entries: list[dict],
+                 t_leaves: list, policy: IOPolicy) -> list:
+    """Each leaf's bytes from the stream, verified against the manifest's
+    digest and put on the device; `device_put` of leaf k overlaps with the
+    prefetch of the leaves after it."""
+    read = getattr(stream, "readview", stream.read)
+    out = []
+    for meta, entry, tmpl in zip(files, entries, t_leaves):
+        # readview: a leaf inside one cached block decodes zero-copy
+        # (np.frombuffer over the block buffer's memoryview).
+        with trace.span("restore.read"):
+            raw = read(meta.size)
+        if policy.verify != "off":
+            # End-to-end: the digest minted over the serialized leaf at save
+            # time must match the bytes about to become model state —
+            # whatever path they took (store, cache tiers, peers). Manifests
+            # predating digests verify nothing (entry.get -> None).
+            with trace.span("restore.verify"):
+                check_block(raw, entry.get("digest"),
+                            what=f"checkpoint leaf {entry['key']}")
+        with trace.span("restore.put"):
+            arr = np.frombuffer(
+                raw, dtype=_dtype_from_str(entry["dtype"])
+            ).reshape(entry["shape"])
+            out.append(jax.device_put(arr, getattr(tmpl, "sharding", None)))
+    return out
+
+
+# Spans whose self time is the restore's host work, not waiting.
+RESTORE_HOST_SPANS = ("restore.open", "restore.verify", "restore.put", "restore.close")
+
+
+def restore_breakdown(rec: trace.Recording) -> dict[str, float]:
+    """Per restore kept in `rec`: seconds of serial metadata requests
+    (`meta_s`), in the stream's `read` for leaf bytes (`wait_s`) and of
+    host work (`host_s`), and the store requests made (`requests`)."""
+    c = rec.counters
+    n = c["restore.count"]
+    return {
+        "meta_s": rec.total("restore.meta") / n,
+        "wait_s": rec.total("restore.read") / n,
+        "host_s": sum(rec.self_s(name) for name in RESTORE_HOST_SPANS) / n,
+        "requests": (c["restore.meta_requests"] + c["restore.store_requests"]) / n,
+    }
 
 
 def gc_checkpoints(store: ObjectStore | str, prefix: str,

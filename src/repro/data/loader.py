@@ -20,7 +20,6 @@ from __future__ import annotations
 
 import queue
 import threading
-import time
 import warnings
 from dataclasses import dataclass
 
@@ -31,7 +30,7 @@ from repro.data.tokens import TokenStreamReader
 from repro.io import IOPolicy, PrefetchFS
 from repro.store.base import ObjectMeta, ObjectStore
 from repro.store.tiers import CacheTier
-from repro.utils import get_logger
+from repro.utils import get_logger, trace
 
 log = get_logger("data.loader")
 
@@ -208,15 +207,14 @@ class PrefetchingDataLoader:
 
 class DeviceFeeder:
     """Host->device double buffering: keeps `depth` batches resident on
-    device ahead of the consumer (the second overlap level)."""
+    device ahead of the consumer (the second overlap level). Each batch's
+    `device_put` is a `feeder.put` span on the feeder's thread."""
 
     _STOP = object()
 
-    def __init__(self, batch_iter, depth: int = 2, sharding=None,
-                 observe=None) -> None:
+    def __init__(self, batch_iter, depth: int = 2, sharding=None) -> None:
         self.q: queue.Queue = queue.Queue(maxsize=depth)
         self.sharding = sharding
-        self.observe = observe
         self._err: list[BaseException] = []
         self._stop = threading.Event()
         self._thread = threading.Thread(
@@ -229,12 +227,10 @@ class DeviceFeeder:
             for host_batch in batch_iter:
                 if self._stop.is_set():
                     break
-                t0 = time.perf_counter()
-                dev = jax.tree.map(
-                    lambda x: jax.device_put(x, self.sharding), host_batch
-                )
-                if self.observe:
-                    self.observe(time.perf_counter() - t0)
+                with trace.span("feeder.put"):
+                    dev = jax.tree.map(
+                        lambda x: jax.device_put(x, self.sharding), host_batch
+                    )
                 self.q.put(dev)
         except BaseException as e:  # repro: allow[RP005] — stashed; __iter__ re-raises
             self._err.append(e)

@@ -17,12 +17,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.ckpt.manager import restore_checkpoint, save_checkpoint
+from repro.ckpt.manager import restore_breakdown, restore_checkpoint, save_checkpoint
 from repro.configs import get_config
 from repro.io import IOPolicy, open_store
 from repro.launch.compile_cache import use_compile_cache
 from repro.models import make_model
-from repro.utils import get_logger
+from repro.utils import get_logger, trace
 
 log = get_logger("launch.serve")
 
@@ -74,19 +74,23 @@ def main(argv: list[str] | None = None) -> ServeRun:
     params = model.init(jax.random.key(0))
     save_checkpoint(store, "weights", 0, params,
                     policy=IOPolicy(write_depth=4))
-    t0 = time.perf_counter()
-    params, _ = restore_checkpoint(
-        store, "weights", params,
-        policy=IOPolicy(engine=args.restore_mode, depth=2,
-                        max_depth=8 if args.autotune else None,
-                        autotune=args.autotune,
-                        eviction_interval_s=0.2),
-        cache_dir=args.cache_dir,
-    )
-    jax.block_until_ready(params)
-    restore_s = time.perf_counter() - t0
+    with trace.recording() as rec:
+        t0 = time.perf_counter()
+        params, _ = restore_checkpoint(
+            store, "weights", params,
+            policy=IOPolicy(engine=args.restore_mode, depth=2,
+                            max_depth=8 if args.autotune else None,
+                            autotune=args.autotune,
+                            eviction_interval_s=0.2),
+            cache_dir=args.cache_dir,
+        )
+        jax.block_until_ready(params)
+        restore_s = time.perf_counter() - t0
+    parts = restore_breakdown(rec)
     print(f"weight restore ({args.restore_mode}): {restore_s:.2f}s"
           + (f" [cache: {args.cache_dir}]" if args.cache_dir else ""))
+    print(f"  meta {parts['meta_s']:.2f}s, wait {parts['wait_s']:.2f}s, "
+          f"host {parts['host_s']:.2f}s, {parts['requests']:.0f} store requests")
     if args.quant == "int8":
         from repro.models.quant import quantize_params
 

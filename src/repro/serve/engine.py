@@ -16,14 +16,14 @@ sequential-object-stream case.
 from __future__ import annotations
 
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.models.api import Model
-from repro.utils import get_logger
+from repro.utils import get_logger, trace
 
 log = get_logger("serve.engine")
 
@@ -34,6 +34,7 @@ class Request:
     prompt: np.ndarray          # (prompt_len,) int32 token ids
     max_new_tokens: int
     eos_id: int | None = None
+    submitted_s: float | None = None   # set by `ServeEngine.submit`
 
 
 @dataclass
@@ -41,7 +42,7 @@ class RequestResult:
     rid: int
     tokens: np.ndarray          # generated ids (<= max_new_tokens)
     prompt_len: int
-    latency_s: float
+    first_token_s: float        # from submit to the first token on the host
 
 
 @dataclass
@@ -97,7 +98,7 @@ class ServeEngine:
         return cls(model, params, max_batch=max_batch, pad_id=pad_id)
 
     def submit(self, req: Request) -> None:
-        self.queue.append(req)
+        self.queue.append(replace(req, submitted_s=time.perf_counter()))
 
     # ------------------------------------------------------------------ #
     def _admit_wave(self) -> list[Request]:
@@ -126,7 +127,6 @@ class ServeEngine:
         t_start = time.perf_counter()
         while self.queue and (max_waves is None or self.stats.waves < max_waves):
             wave = self._admit_wave()
-            t_wave = time.perf_counter()
             batch_ids, lens = self._stack_prompts(wave)
             b, s = batch_ids.shape
             budget = max(r.max_new_tokens for r in wave)
@@ -135,19 +135,22 @@ class ServeEngine:
             # Prefill with decode headroom.
             from repro.models import lm as LM
 
-            caches = LM.make_stack_cache(cfg, b, s + budget)
-            h, caches, _ = LM.lm_hidden(
-                self.params, cfg, jnp.asarray(batch_ids),
-                caches=caches, update_cache=True,
-                q_chunk=min(512, s),
-            )
-            logits = LM.logits_from_hidden(self.params, cfg, h[:, -1:, :])[:, 0]
+            with trace.span("serve.prefill"):
+                caches = LM.make_stack_cache(cfg, b, s + budget)
+                h, caches, _ = LM.lm_hidden(
+                    self.params, cfg, jnp.asarray(batch_ids),
+                    caches=caches, update_cache=True,
+                    q_chunk=min(512, s),
+                )
+                logits = LM.logits_from_hidden(self.params, cfg, h[:, -1:, :])[:, 0]
 
             generated = np.full((b, budget), -1, np.int64)
             done = np.zeros(b, bool)
-            tok = np.asarray(
-                jnp.argmax(logits[:, : cfg.vocab_size], axis=-1)
-            )
+            with trace.span("serve.first_tokens"):
+                tok = np.asarray(
+                    jnp.argmax(logits[:, : cfg.vocab_size], axis=-1)
+                )
+            t_first = time.perf_counter()
             for i, r in enumerate(wave):
                 generated[i, 0] = tok[i]
                 if (r.eos_id is not None and tok[i] == r.eos_id) or \
@@ -155,32 +158,32 @@ class ServeEngine:
                     done[i] = True
 
             step = 1
-            while not done.all() and step < budget:
-                logits_t, caches = self._decode(
-                    self.params, jnp.asarray(tok[:, None], jnp.int32),
-                    caches, s + step - 1,
-                )
-                self.stats.decode_steps += 1
-                tok = np.asarray(
-                    jnp.argmax(logits_t[:, : cfg.vocab_size], axis=-1)
-                )
-                for i, r in enumerate(wave):
-                    if done[i]:
-                        continue
-                    generated[i, step] = tok[i]
-                    if (r.eos_id is not None and tok[i] == r.eos_id) or \
-                            step + 1 >= r.max_new_tokens:
-                        done[i] = True
-                step += 1
+            with trace.span("serve.decode"):
+                while not done.all() and step < budget:
+                    logits_t, caches = self._decode(
+                        self.params, jnp.asarray(tok[:, None], jnp.int32),
+                        caches, s + step - 1,
+                    )
+                    self.stats.decode_steps += 1
+                    tok = np.asarray(
+                        jnp.argmax(logits_t[:, : cfg.vocab_size], axis=-1)
+                    )
+                    for i, r in enumerate(wave):
+                        if done[i]:
+                            continue
+                        generated[i, step] = tok[i]
+                        if (r.eos_id is not None and tok[i] == r.eos_id) or \
+                                step + 1 >= r.max_new_tokens:
+                            done[i] = True
+                    step += 1
 
-            latency = time.perf_counter() - t_wave
             for i, r in enumerate(wave):
                 toks = generated[i][generated[i] >= 0]
                 results.append(RequestResult(
                     rid=r.rid,
                     tokens=toks.astype(np.int64),
                     prompt_len=int(lens[i]),
-                    latency_s=latency,
+                    first_token_s=t_first - r.submitted_s,
                 ))
                 self.stats.generated_tokens += len(toks)
             self.stats.waves += 1

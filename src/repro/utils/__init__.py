@@ -1,5 +1,4 @@
 from repro.utils.hashing import rendezvous_owner
 from repro.utils.logging import get_logger
-from repro.utils.timing import Timer, Stopwatch
 
-__all__ = ["get_logger", "Timer", "Stopwatch", "rendezvous_owner"]
+__all__ = ["get_logger", "rendezvous_owner"]
