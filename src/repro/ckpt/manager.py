@@ -18,10 +18,14 @@ registry URIs (``"sims3://ckpt?latency_ms=10"``) — see
 
 Restore: the leaf objects form exactly the sequential multi-file stream
 Rolling Prefetch was built for; they stream through the `PrefetchFS`
-facade. `policy=IOPolicy(engine="rolling")` (the default) runs the
-three-thread engine, so fetching leaf k+1..k+d from the store overlaps
-with deserializing + `device_put`-ing leaf k — the paper's
-max(T_cloud, T_comp) pipeline applied to checkpoint load.
+facade. The stream's layout comes from the manifest alone: each leaf's
+size is its entry's ``prod(shape) * itemsize``, the bytes `save_checkpoint`
+wrote, so a restore asks the store for no sizes. Its only metadata
+requests are the manifest GET and, without a ``step``, the LIST.
+`policy=IOPolicy(engine="rolling")` (the default) runs the three-thread
+engine, so fetching leaf k+1..k+d from the store overlaps with
+deserializing + `device_put`-ing leaf k — the paper's max(T_cloud, T_comp)
+pipeline applied to checkpoint load.
 `engine="sequential"` is the S3Fs-style baseline the benchmarks A/B
 against. The legacy `mode=` string kwarg still works and warns.
 
@@ -29,16 +33,18 @@ Elastic: the restore template's shardings may come from a different mesh
 than save time; `device_put` reshards each leaf onto the new topology.
 
 Each restore is one `restore` span (`repro.utils.trace`) whose children
-split it into the serial metadata requests (`restore.meta`), the facade's
-set-up (`restore.open`), and per leaf the stream's `read` of its bytes
-(`restore.read`), the digest check (`restore.verify`) and the decode and
-`device_put` (`restore.put`), then `restore.close`.
+split it into the serial metadata requests (`restore.meta`: the LIST and
+the manifest), the facade's set-up (`restore.open`), and per leaf the
+stream's `read` of its bytes (`restore.read`), the digest check
+(`restore.verify`) and the decode and `device_put` (`restore.put`), then
+`restore.close`.
 """
 
 from __future__ import annotations
 
 import contextlib
 import json
+import math
 import re
 import threading
 import time
@@ -59,7 +65,7 @@ log = get_logger("ckpt")
 
 MANIFEST = "MANIFEST.json"
 
-# Metadata ops (list/size/get-manifest) retry through the shared
+# Metadata ops (list/get-manifest) retry through the shared
 # resilience layer — full-jitter backoff, so a fleet of restarting
 # workers hitting the same manifest does not re-collide in one backoff
 # window. Bulk leaf reads retry inside the reader engines themselves.
@@ -334,7 +340,11 @@ def restore_checkpoint(
 def _restore_meta(store: ObjectStore, prefix: str,
                   step: int | None) -> tuple[dict, list[ObjectMeta]]:
     """The restore's serial metadata requests: the LIST that finds the latest
-    step (when `step` is None), the manifest GET and one size per leaf."""
+    step (when `step` is None) and the manifest GET. Each leaf's size comes
+    from its manifest entry, with no request: `save_checkpoint` writes a leaf
+    as its raw bytes, `prod(shape) * itemsize` of them. A leaf object shorter
+    than that fails as a truncated read; one with other bytes fails its
+    digest unless `verify="off"`."""
     if step is None:
         trace.count("restore.meta_requests")
         step = latest_step(store, prefix)
@@ -342,10 +352,10 @@ def _restore_meta(store: ObjectStore, prefix: str,
             raise FileNotFoundError(f"no checkpoint under {prefix!r}")
     trace.count("restore.meta_requests")
     manifest = _load_manifest(store, prefix, step)
-    files = []
-    for e in manifest["leaves"]:
-        trace.count("restore.meta_requests")
-        files.append(ObjectMeta(e["key"], _with_retries(lambda k=e["key"]: store.size(k))))
+    files = [
+        ObjectMeta(e["key"], math.prod(e["shape"]) * _dtype_from_str(e["dtype"]).itemsize)
+        for e in manifest["leaves"]
+    ]
     return manifest, files
 
 

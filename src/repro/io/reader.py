@@ -14,7 +14,7 @@ from dataclasses import dataclass
 from typing import Protocol, runtime_checkable
 
 from repro.core.plan import BlockPlan
-from repro.store.base import ObjectMeta, ObjectStore
+from repro.store.base import ObjectMeta, ObjectStore, StoreError
 
 
 @runtime_checkable
@@ -86,6 +86,13 @@ class DirectReader:
                                         block.start + hi)
             self.stats.fetch_s += time.perf_counter() - t0
             self.stats.requests += 1
+            if not data:
+                # The object ends before the size it was opened with: asking
+                # again from the same offset would never make progress.
+                raise StoreError(
+                    f"{block.key} ends at byte {block.start + lo}, "
+                    f"short of its size {block.end}"
+                )
             out.extend(data)
             self._pos += len(data)
         self.stats.bytes_read += len(out)

@@ -246,6 +246,56 @@ class TestCheckpoint:
         assert saved == [False, True, False, True, False, True]
         assert latest_step(store, "ckpt") == 6
 
+    @pytest.mark.parametrize("step", [3, None], ids=["given", "latest"])
+    def test_restore_takes_leaf_sizes_from_manifest(self, step):
+        """A restore asks the store for no object sizes, and the manifest's
+        sizes are byte-exact for 0-d, empty, bfloat16 and int32 leaves."""
+
+        class CountingStore(SimS3Store):
+            size_calls = 0
+
+            def size(self, key):
+                self.size_calls += 1
+                return super().size(key)
+
+        store = CountingStore(link=LinkModel())
+        state = {
+            "scalar": jnp.asarray(2.5, jnp.float32),
+            "empty": jnp.zeros((0, 4), jnp.float32),
+            "half": jax.random.normal(jax.random.key(1), (5, 7), jnp.bfloat16),
+            "ids": jnp.arange(-6, 15, dtype=jnp.int32).reshape(3, 7),
+        }
+        save_checkpoint(store, "ckpt", 3, state)
+        restored, manifest = restore_checkpoint(
+            store, "ckpt", state, step=step, blocksize=16)
+        assert store.size_calls == 0
+        assert manifest["step"] == 3
+        for name, leaf in state.items():
+            got = np.asarray(restored[name])
+            assert got.dtype == leaf.dtype and got.shape == leaf.shape, name
+            assert got.tobytes() == np.asarray(leaf).tobytes(), name
+
+    @pytest.mark.parametrize("engine", ["rolling", "sequential", "direct"])
+    @pytest.mark.parametrize("verify", ["edges", "off"])
+    def test_truncated_leaf_object_fails_restore(self, engine, verify):
+        """A leaf object cut short in the store after the save fails the
+        restore; it never comes back as a short or zero-padded leaf."""
+        from repro.ckpt.manager import _leaf_key
+        from repro.io import IOPolicy
+        from repro.store.base import StoreError
+
+        store = make_store({})
+        state = _state()
+        save_checkpoint(store, "ckpt", 2, state)
+        # Leaves flatten by key: params/b, params/w, step; cut w mid-block.
+        key = _leaf_key("ckpt", 2, 1)
+        full = store.backing.get(key)
+        store.backing.put(key, full[: len(full) - 100])
+        policy = IOPolicy(engine=engine, verify=verify, blocksize=1024,
+                          retry_backoff_s=0.001)
+        with pytest.raises(StoreError):
+            restore_checkpoint(store, "ckpt", state, policy=policy)
+
     def test_restore_with_abstract_template(self):
         """Templates may be ShapeDtypeStructs (the dry-run/elastic path)."""
         store = make_store({})

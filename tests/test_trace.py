@@ -151,7 +151,7 @@ def test_restore_spans_and_request_counters(step):
     assert {s.parent for s in rec.spans if s.name != "restore"} == {"restore"}
     c = rec.counters
     assert c["restore.count"] == 1
-    assert c["restore.meta_requests"] == leaves + 1 + (step is None)
+    assert c["restore.meta_requests"] == 1 + (step is None)
     assert c["restore.meta_requests"] + c["restore.store_requests"] == made
     assert c["restore.bytes_fetched"] == sum(x.nbytes for x in jax.tree.leaves(state))
     parts = restore_breakdown(rec)
