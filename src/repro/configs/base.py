@@ -50,6 +50,7 @@ class ModelConfig:
     out_bias: bool = False        # bias on attn-out / MLP projections
     parallel_block: bool = False  # Cohere: attn + FFN share the input norm
     qk_norm: bool = False
+    attention_multiplier: float = 0.0  # softmax scale; 0 -> head_dim ** -0.5
     act: str = "silu"             # silu (SwiGLU) | gelu (plain / GeGLU)
     glu: bool = True              # gated FFN
     tie_embeddings: bool = False
@@ -79,6 +80,7 @@ class ModelConfig:
     ssm_ngroups: int = 1
     ssm_chunk: int = 128
     ssm_conv_kernel: int = 4
+    ssm_conv_bias: bool = False   # bias on the depthwise convs (Mamba-2 conv1d)
 
     # encoder-decoder
     is_encdec: bool = False
@@ -237,6 +239,7 @@ _ARCH_MODULES = [
     "granite_moe_3b_a800m",
     "dbrx_132b",
     "mamba2_1_3b",
+    "granite_4_0_h_micro",
 ]
 
 _LOADED = False

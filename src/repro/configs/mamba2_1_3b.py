@@ -1,8 +1,9 @@
 """mamba2-1.3b — attention-free SSM with state-space duality (SSD).
 
 48L pure Mamba-2 blocks (no attention, no separate FFN), d_model 2048,
-expand 2 (d_inner 4096), head_dim 64 (64 ssm heads), state 128, conv 4,
-vocab 50280. RMSNorm, tied embeddings. Runs long_500k (sub-quadratic).
+expand 2 (d_inner 4096), head_dim 64 (64 ssm heads), state 128, conv 4
+with bias, vocab 50280. RMSNorm, tied embeddings. Runs long_500k
+(sub-quadratic).
 [arXiv:2405.21060; unverified]
 """
 
@@ -29,6 +30,7 @@ CONFIG = register(
         ssm_ngroups=1,
         ssm_chunk=256,
         ssm_conv_kernel=4,
+        ssm_conv_bias=True,
         source="arXiv:2405.21060",
     )
 )

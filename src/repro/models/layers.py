@@ -159,6 +159,7 @@ def _attn_core(
     v: jax.Array,
     *,
     causal: bool,
+    scale: float,          # softmax scale of the scores
     q_offset,              # scalar: global position of q[0]
     kv_valid_len=None,     # scalar: mask kv positions >= this
     q_chunk: int = 512,
@@ -175,7 +176,6 @@ def _attn_core(
     b, sq, hq, dh = q.shape
     sk, hkv = k.shape[1], k.shape[2]
     g = hq // hkv
-    scale = dh ** -0.5
     head_mode = allow_head_shard and _head_shardable(hq)
     kv_pos = jnp.arange(sk, dtype=jnp.int32)
 
@@ -310,6 +310,7 @@ def attention(
     out = _attn_core(
         q, k, v,
         causal=causal and kv_source is None,
+        scale=cfg.attention_multiplier or cfg.head_dim ** -0.5,
         q_offset=q_offset,
         kv_valid_len=kv_len,
         q_chunk=q_chunk,

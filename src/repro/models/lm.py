@@ -5,7 +5,10 @@ block pattern and lowered as ONE `jax.lax.scan` over stacked per-period
 parameters — HLO size is O(pattern), not O(depth), keeping 40-cell x
 2-mesh dry-run compiles tractable. Training remats each period (inputs
 saved, internals recomputed), bounding live activations to the residual
-stream.
+stream; in a period of more than one block it remats each block instead,
+so the backward pass holds one block's internals at a time, not the whole
+period's. Named scopes (`attn`, `mamba`, `ffn`, `loss`) tag each layer's
+operations in the device trace's metadata.
 """
 
 from __future__ import annotations
@@ -89,31 +92,36 @@ def block_fwd(
 
     h = L.apply_norm(p["norm1"], cfg, x)
     if bd.mixer == "attn":
-        attn_out, kv = L.attention(
-            p["attn"], cfg, h,
-            positions=positions,
-            causal=causal,
-            cache=None if cache is None else cache.get("attn"),
-            update_cache=update_cache,
-            q_chunk=q_chunk,
-        )
+        with jax.named_scope("attn"):
+            attn_out, kv = L.attention(
+                p["attn"], cfg, h,
+                positions=positions,
+                causal=causal,
+                cache=None if cache is None else cache.get("attn"),
+                update_cache=update_cache,
+                q_chunk=q_chunk,
+            )
         if kv is not None:
             new_cache["attn"] = kv
     else:
-        attn_out, mc = S.mamba_block(
-            p["mamba"], cfg, h,
-            cache=None if cache is None else cache.get("mamba"),
-            update_cache=update_cache,
-        )
+        with jax.named_scope("mamba"):
+            attn_out, mc = S.mamba_block(
+                p["mamba"], cfg, h,
+                cache=None if cache is None else cache.get("mamba"),
+                update_cache=update_cache,
+            )
         if mc is not None:
             new_cache["mamba"] = mc
 
+    def ffn(h):
+        with jax.named_scope("ffn"):
+            if bd.ffn == "dense":
+                return L.mlp(p["ffn"], cfg, h), aux
+            return M.moe(p["ffn"], cfg, h)
+
     if cfg.parallel_block and bd.ffn is not None:
         # Cohere: attn and FFN both read the same normed input.
-        if bd.ffn == "dense":
-            ffn_out = L.mlp(p["ffn"], cfg, h)
-        else:
-            ffn_out, aux = M.moe(p["ffn"], cfg, h)
+        ffn_out, aux = ffn(h)
         x = x + rm * (attn_out + ffn_out)
         return x, new_cache, aux
 
@@ -143,11 +151,7 @@ def block_fwd(
             new_cache["cross"] = cache["cross"]
 
     if bd.ffn is not None:
-        h2 = L.apply_norm(p["norm2"], cfg, x)
-        if bd.ffn == "dense":
-            ffn_out = L.mlp(p["ffn"], cfg, h2)
-        else:
-            ffn_out, aux = M.moe(p["ffn"], cfg, h2)
+        ffn_out, aux = ffn(L.apply_norm(p["norm2"], cfg, x))
         x = x + rm * ffn_out
     return x, new_cache, aux
 
@@ -215,6 +219,22 @@ def stack_fwd(
     Returns (x, new_caches, total_aux)."""
     pattern = pattern if pattern is not None else cfg.pattern
     periods = jax.tree.leaves(p_stack)[0].shape[0]
+    # One block: remat the period. Several: remat each block, so that the
+    # backward pass recomputes one block's internals at a time.
+    per_block = remat and len(pattern) > 1
+
+    def block(bd, pb, x, cache):
+        def fwd(pb, x, cache):
+            return block_fwd(
+                pb, cfg, bd, x,
+                positions=positions,
+                cache=cache,
+                update_cache=update_cache,
+                enc_hidden=enc_hidden,
+                causal=causal,
+                q_chunk=q_chunk,
+            )
+        return (jax.checkpoint(fwd) if per_block else fwd)(pb, x, cache)
 
     def period_fn(carry, xs):
         x, caches_all, aux_sum = carry
@@ -228,14 +248,8 @@ def stack_fwd(
             )
         new_pc: dict = {}
         for i, bd in enumerate(pattern):
-            x, nc, aux = block_fwd(
-                pp[f"block{i}"], cfg, bd, x,
-                positions=positions,
-                cache=None if pc is None else pc[f"block{i}"],
-                update_cache=update_cache,
-                enc_hidden=enc_hidden,
-                causal=causal,
-                q_chunk=q_chunk,
+            x, nc, aux = block(
+                bd, pp[f"block{i}"], x, None if pc is None else pc[f"block{i}"]
             )
             new_pc[f"block{i}"] = nc
             aux_sum = aux_sum + aux
@@ -248,7 +262,7 @@ def stack_fwd(
             )
         return (x, caches_all, aux_sum), None
 
-    fn = jax.checkpoint(period_fn) if remat else period_fn
+    fn = jax.checkpoint(period_fn) if remat and not per_block else period_fn
     (x, new_caches, aux_sum), _ = jax.lax.scan(
         fn,
         (x, caches, jnp.zeros((), jnp.float32)),
@@ -348,7 +362,8 @@ def lm_loss(
     *, q_chunk: int = 512, loss_chunk: int = 512, remat: bool = True,
 ) -> jax.Array:
     h, _, aux = lm_hidden(p, cfg, inputs, q_chunk=q_chunk, remat=remat)
-    loss = chunked_xent(p, cfg, h, labels, chunk=loss_chunk)
+    with jax.named_scope("loss"):
+        loss = chunked_xent(p, cfg, h, labels, chunk=loss_chunk)
     if cfg.is_moe:
         loss = loss + cfg.moe_aux_loss_weight * aux / max(cfg.num_layers, 1)
     return loss

@@ -133,9 +133,11 @@ def ssd_decode_step(
 # --------------------------------------------------------------------------- #
 # Causal depthwise conv (shift-and-add; K is tiny)
 # --------------------------------------------------------------------------- #
-def causal_conv(x: jax.Array, w: jax.Array, state: jax.Array | None = None):
-    """x: (B, S, C); w: (K, C). Returns (y (B,S,C), new_state (B,K-1,C)).
-    `state` carries the last K-1 inputs for decode continuity."""
+def causal_conv(x: jax.Array, w: jax.Array, state: jax.Array | None = None,
+                bias: jax.Array | None = None):
+    """x: (B, S, C); w: (K, C); bias: (C,) or None. Returns (y (B,S,C),
+    new_state (B,K-1,C)). `state` carries the last K-1 inputs for decode
+    continuity, so prefill and each decode step add the bias alike."""
     k = w.shape[0]
     if state is None:
         state = jnp.zeros((x.shape[0], k - 1, x.shape[2]), x.dtype)
@@ -143,6 +145,8 @@ def causal_conv(x: jax.Array, w: jax.Array, state: jax.Array | None = None):
     y = sum(
         xp[:, i : i + x.shape[1], :] * w[i][None, None, :] for i in range(k)
     )
+    if bias is not None:
+        y = y + bias[None, None, :]
     new_state = xp[:, -(k - 1):, :] if k > 1 else state
     return y.astype(x.dtype), new_state
 
@@ -171,7 +175,7 @@ def mamba_cache_logical_axes() -> MambaCache:
 def mamba_spec(cfg: ModelConfig) -> dict:
     d, din = cfg.d_model, cfg.d_inner
     g, n, h, k = cfg.ssm_ngroups, cfg.ssm_state, cfg.ssm_nheads, cfg.ssm_conv_kernel
-    return {
+    spec = {
         "w_z": ParamSpec((d, din), ("fsdp", "tp"), ("fan_in", d)),
         "w_x": ParamSpec((d, din), ("fsdp", "tp"), ("fan_in", d)),
         "w_b": ParamSpec((d, g * n), ("fsdp", None), ("fan_in", d)),
@@ -186,6 +190,11 @@ def mamba_spec(cfg: ModelConfig) -> dict:
         "norm_scale": ParamSpec((din,), ("tp",), "ones"),
         "w_out": ParamSpec((din, d), ("tp", "fsdp"), ("fan_in", din)),
     }
+    if cfg.ssm_conv_bias:
+        spec["conv_x_bias"] = ParamSpec((din,), ("tp",), "zeros")
+        spec["conv_b_bias"] = ParamSpec((g * n,), (None,), "zeros")
+        spec["conv_c_bias"] = ParamSpec((g * n,), (None,), "zeros")
+    return spec
 
 
 def make_mamba_cache(cfg: ModelConfig, batch: int, dtype=jnp.bfloat16) -> MambaCache:
@@ -227,9 +236,15 @@ def mamba_block(
     z = constrain(z, "batch", None, "tp")
 
     conv_state = (cache.conv_x, cache.conv_b, cache.conv_c) if cache else (None,) * 3
-    xs, st_x = causal_conv(xs, p["conv_x"].astype(dt), conv_state[0])
-    bp, st_b = causal_conv(bp, p["conv_b"].astype(dt), conv_state[1])
-    cp, st_c = causal_conv(cp, p["conv_c"].astype(dt), conv_state[2])
+
+    def conv(u, name, state):
+        bias = p.get(f"{name}_bias")
+        return causal_conv(u, p[name].astype(dt), state,
+                           None if bias is None else bias.astype(dt))
+
+    xs, st_x = conv(xs, "conv_x", conv_state[0])
+    bp, st_b = conv(bp, "conv_b", conv_state[1])
+    cp, st_c = conv(cp, "conv_c", conv_state[2])
     xs, bp, cp = jax.nn.silu(xs), jax.nn.silu(bp), jax.nn.silu(cp)
 
     dt_val = jax.nn.softplus(

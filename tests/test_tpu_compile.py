@@ -9,6 +9,7 @@ the description happens in a fixture, never at import.
 
 from __future__ import annotations
 
+import dataclasses
 import os
 
 import jax
@@ -99,6 +100,27 @@ def test_prefill_and_decode_compile_for_one_chip(one_chip, smollm):
     ids = jax.ShapeDtypeStruct((SERVE_BATCH, 1), jnp.int32, sharding=one_chip)
     pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     _assert_fits(jax.jit(smollm.decode_step).lower(params, ids, caches, pos).compile())
+
+
+def test_granite_train_step_fills_one_chip(one_chip):
+    """The granite cell's step: one period (10 of 40 layers) and an eighth
+    of the vocabulary at 1 x 8192. Its fp32 state and AdamW moments alone
+    are 9.27 GB; per-block remat must keep the rest under 16 GB, and the
+    cell must use at least three quarters of the chip."""
+    cfg = dataclasses.replace(get_config("granite-4.0-h-micro"), num_layers=10,
+                              vocab_size=12544)
+    model = make_model(cfg)
+    state = _on(one_chip, jax.eval_shape(
+        lambda: init_train_state(model, jax.random.key(0))))
+    tokens = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip)
+    step = jax.jit(
+        build_train_step(model, AdamWConfig(total_steps=20, warmup_steps=1),
+                         StepConfig(q_chunk=512, loss_chunk=512)),
+        donate_argnums=(0,))
+    compiled = step.lower(state, {"inputs": tokens, "labels": tokens}).compile()
+    _assert_fits(compiled)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes >= 0.75 * HBM_BYTES
 
 
 def test_flash_attention_lowers_to_a_kernel(one_chip):
