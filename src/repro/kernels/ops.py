@@ -15,13 +15,14 @@ from repro.kernels import flash_attention as _fa
 from repro.kernels import ssd_scan as _ssd
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
-                                             "interpret"))
-def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = False):
-    """q: (B, Hq, Sq, D); k/v: (B, Hkv, Sk, D) -> (B, Hq, Sq, D)."""
+@functools.partial(jax.jit, static_argnames=("causal", "scale", "block_q",
+                                             "block_k", "interpret"))
+def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
+                    block_q: int = _fa.BLOCK, block_k: int = _fa.BLOCK,
+                    interpret: bool = False):
+    """q: (B, Hq, Sq, D); k/v: (B, Hkv, Sk, D) -> (B, Hq, Sq, D); has a VJP."""
     return _fa.flash_attention(
-        q, k, v, causal=causal, block_q=block_q, block_k=block_k,
+        q, k, v, causal=causal, scale=scale, block_q=block_q, block_k=block_k,
         interpret=interpret,
     )
 

@@ -11,6 +11,10 @@ Design notes for multi-pod scale:
   reductions + all-reduce under GSPMD (flash-decoding structure).
 * Queries are processed in chunks via `lax.scan` (online, bounded memory)
   so 32k prefill and 4k train never materialize full S×S scores.
+* Cache-less causal self-attention on one TPU device goes to the fused
+  Pallas kernel (`repro.kernels.flash_attention`): no score tile reaches
+  HBM, and blocks above the diagonal are skipped. Every other call, and
+  every platform but the TPU, takes the chunked XLA path.
 * All matmuls run in bf16 with fp32 softmax/norm accumulations.
 """
 
@@ -23,8 +27,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels import ops as kernel_ops
+from repro.kernels.flash_attention import BLOCK as KERNEL_BLOCK, HEAD_DIMS as KERNEL_HEAD_DIMS
 from repro.models.spec import ParamSpec
-from repro.sharding.rules import constrain
+from repro.sharding.rules import constrain, current_rules
+from repro.utils import trace
 
 COMPUTE_DTYPE = jnp.bfloat16
 
@@ -143,14 +150,34 @@ def attention_spec(cfg: ModelConfig, cross: bool = False) -> dict:
 def _head_shardable(hq: int) -> bool:
     """True when the q-head count divides the tensor axis under the ambient
     rules — selects the collective-free head-sharded attention path."""
-    from repro.sharding.rules import current_rules
-
     rules = current_rules()
     return (
         rules is not None
         and rules.mesh is not None
         and rules.resolve_dim("heads", hq) is not None
     )
+
+
+def _kernel_fits(q: jax.Array, k: jax.Array, *, causal: bool, q_offset,
+                 kv_valid_len) -> bool:
+    """True for what the fused kernel computes: causal self-attention with
+    no cache over the call's own positions from 0 (`q_offset` the int 0),
+    in whole kernel blocks, of a head size it takes, with no mesh of more
+    than one device to shard it."""
+    rules = current_rules()
+    s = q.shape[1]
+    return (causal and kv_valid_len is None
+            and isinstance(q_offset, int) and q_offset == 0
+            and k.shape[1] == s and s % KERNEL_BLOCK == 0
+            and q.shape[-1] in KERNEL_HEAD_DIMS
+            and (rules is None or rules.mesh is None or rules.mesh.size == 1))
+
+
+def _attn_kernel(q: jax.Array, k: jax.Array, v: jax.Array, scale: float) -> jax.Array:
+    """The fused causal kernel, in the model's (B, S, H, D) layout."""
+    o = kernel_ops.flash_attention(q.swapaxes(1, 2), k.swapaxes(1, 2),
+                                   v.swapaxes(1, 2), causal=True, scale=scale)
+    return o.swapaxes(1, 2)
 
 
 def _attn_core(
@@ -160,10 +187,40 @@ def _attn_core(
     *,
     causal: bool,
     scale: float,          # softmax scale of the scores
-    q_offset,              # scalar: global position of q[0]
+    q_offset,              # scalar: global position of q[0]; the int 0 when
+                           # q and k are the call's own positions from 0
     kv_valid_len=None,     # scalar: mask kv positions >= this
     q_chunk: int = 512,
     allow_head_shard: bool = True,
+) -> jax.Array:
+    """Attention by the fused kernel where it fits and the lowering is for
+    a TPU, else by `_attn_xla`. Counts `attn.kernel` or `attn.xla` in
+    `repro.utils.trace` for the path each lowered call holds."""
+    def xla(q, k, v):
+        out = _attn_xla(q, k, v, causal=causal, scale=scale, q_offset=q_offset,
+                        kv_valid_len=kv_valid_len, q_chunk=q_chunk,
+                        allow_head_shard=allow_head_shard)
+        return trace.tag(out, "attn.xla")
+
+    if not _kernel_fits(q, k, causal=causal, q_offset=q_offset,
+                        kv_valid_len=kv_valid_len):
+        return xla(q, k, v)
+    return jax.lax.platform_dependent(
+        q, k, v, default=xla,
+        tpu=lambda q, k, v: trace.tag(_attn_kernel(q, k, v, scale), "attn.kernel"))
+
+
+def _attn_xla(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    *,
+    causal: bool,
+    scale: float,
+    q_offset,
+    kv_valid_len,
+    q_chunk: int,
+    allow_head_shard: bool,
 ) -> jax.Array:
     """Chunked online attention; never materializes S_q x S_k at once.
 
@@ -257,7 +314,8 @@ def attention(
     cfg: ModelConfig,
     x: jax.Array,                  # (B, S, D)
     *,
-    positions: jax.Array,          # (S,) or (B, S) global positions of x
+    positions: jax.Array | None,   # (S,) or (B, S) global positions of x;
+                                   # None: x's own, 0..S-1
     causal: bool = True,
     kv_source: jax.Array | None = None,   # cross-attention source (B, S_kv, D)
     cache: KVCache | None = None,
@@ -266,6 +324,10 @@ def attention(
 ) -> tuple[jax.Array, KVCache | None]:
     cfg_rope = cfg.use_rope and kv_source is None
     b, s, _ = x.shape
+    if positions is None:
+        positions, q_offset = jnp.arange(s, dtype=jnp.int32), 0
+    else:
+        q_offset = positions[0] if positions.ndim == 1 else positions[0, 0]
 
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(x.dtype))
     if "bq" in p:
@@ -306,7 +368,6 @@ def attention(
     if cfg_rope:
         q = apply_rope(q, positions, cfg.rope_theta)
 
-    q_offset = positions[0] if positions.ndim == 1 else positions[0, 0]
     out = _attn_core(
         q, k, v,
         causal=causal and kv_source is None,

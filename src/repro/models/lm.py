@@ -78,7 +78,7 @@ def block_fwd(
     bd: BlockDef,
     x: jax.Array,
     *,
-    positions: jax.Array,
+    positions: jax.Array | None,
     cache: dict | None = None,
     update_cache: bool = False,
     enc_hidden: jax.Array | None = None,
@@ -202,7 +202,7 @@ def stack_fwd(
     cfg: ModelConfig,
     x: jax.Array,
     *,
-    positions: jax.Array,
+    positions: jax.Array | None,
     caches: dict | None = None,
     update_cache: bool = False,
     enc_hidden: jax.Array | None = None,
@@ -295,9 +295,6 @@ def lm_hidden(
     positions: jax.Array | None = None,
     caches=None, update_cache=False, q_chunk: int = 512, remat=False,
 ):
-    seq = inputs.shape[1]
-    if positions is None:
-        positions = jnp.arange(seq, dtype=jnp.int32)
     x = lm_inputs_to_hidden(p, cfg, inputs)
     x = constrain(x, "batch", None, "residual")
     x, new_caches, aux = stack_fwd(

@@ -16,7 +16,8 @@ per-block or per-byte loop: the engines count those in `PrefetchStats`.
 While recording, JAX's compile events are counted too: `jax.compile_s` sums
 the seconds spent tracing, lowering and compiling (a persistent-cache load
 happens inside the compile event), and `jax.compiles` counts the programs
-compiled or loaded from the cache.
+compiled or loaded from the cache. `tag(x, name)` counts where a staged
+program goes: once each time the code holding it is lowered.
 """
 
 from __future__ import annotations
@@ -28,6 +29,8 @@ from dataclasses import dataclass, field
 from typing import NamedTuple
 
 import jax
+from jax.extend.core import Primitive
+from jax.interpreters import ad, batching, mlir
 
 # Compile events that JAX times; each program obtained fires the last once.
 _COMPILE_EVENTS = (
@@ -140,6 +143,33 @@ def count(name: str, n: float = 1) -> None:
     session = _session
     if session is not None:
         session.add(name, n)
+
+
+def tag(x: jax.Array, name: str) -> jax.Array:
+    """`x` unchanged; adds 1 to the counter `name` each time the code that
+    computes it is lowered (or run op by op). Only the lowered platform's
+    branch of a `jax.lax.platform_dependent` is lowered, so the counter
+    tells which branch the compiled program holds. A recomputation under
+    `jax.checkpoint` is lowered, and counts, again."""
+    return _tag_p.bind(x, name=name)
+
+
+def _count_and_return(x, *, name):
+    count(name)
+    return x
+
+
+def _tag_lowering(ctx, x, *, name):
+    count(name)
+    return [x]
+
+
+_tag_p = Primitive("trace_tag")
+_tag_p.def_impl(_count_and_return)
+_tag_p.def_abstract_eval(lambda x, *, name: x)
+mlir.register_lowering(_tag_p, _tag_lowering)
+ad.defjvp(_tag_p, lambda g, x, *, name: g)
+batching.defvectorized(_tag_p)
 
 
 @contextlib.contextmanager

@@ -9,10 +9,14 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro.configs import get_config
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ref import attention_ref, ssd_ref
 from repro.kernels.ssd_scan import ssd_scan
+from repro.models import layers as L
 from repro.models.ssd import ssd_chunked
+from repro.sharding.rules import TRAIN_RULES, ShardingRules, use_rules
+from repro.utils import trace
 
 
 def _tol(dtype):
@@ -104,6 +108,132 @@ def test_flash_attention_property(b, hkv, group, sq_blocks, d):
     ref = attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-3, atol=2e-3)
+
+
+# The train cells' attention layouts: (name, hq, hkv, d, scale).
+TRAIN_LAYOUTS = [
+    ("smollm", 9, 3, 64, 0.125),
+    ("olmo", 16, 16, 128, 128 ** -0.5),
+    ("granite", 32, 8, 64, 0.015625),
+]
+
+
+def _bshd_inputs(hq, hkv, d, s=256, seed=0):
+    ks = jax.random.split(jax.random.key(seed), 4)
+    q = jax.random.normal(ks[0], (1, s, hq, d), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (1, s, hkv, d), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (1, s, hkv, d), jnp.bfloat16)
+    w = jax.random.normal(ks[3], (1, s, hq, d), jnp.float32)
+    return q, k, v, w
+
+
+def _value_and_grads(attn, q, k, v, w):
+    """The output and the gradients in q, k and v of sum(attn(q, k, v) * w)."""
+    out, vjp = jax.vjp(attn, q, k, v)
+    return [out, *vjp(w.astype(out.dtype))]
+
+
+def _kernel_bshd(scale, block):
+    def attn(q, k, v):
+        o = flash_attention(q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2),
+                            causal=True, scale=scale, block_q=block, block_k=block,
+                            interpret=True)
+        return o.swapaxes(1, 2)
+    return attn
+
+
+@pytest.mark.parametrize("layout", TRAIN_LAYOUTS, ids=[c[0] for c in TRAIN_LAYOUTS])
+def test_flash_attention_grads_match_xla_path_and_ref(layout):
+    """bf16, causal, two blocks a sequence: the kernel's output and its
+    gradients in q, k and v match `_attn_core`'s XLA path and the float32
+    oracle."""
+    _, hq, hkv, d, scale = layout
+    q, k, v, w = _bshd_inputs(hq, hkv, d)
+    got = _value_and_grads(_kernel_bshd(scale, 128), q, k, v, w)
+    xla = _value_and_grads(
+        lambda q, k, v: L._attn_xla(q, k, v, causal=True, scale=scale, q_offset=0,
+                                    kv_valid_len=None, q_chunk=128, allow_head_shard=True),
+        q, k, v, w)
+
+    def ref_attn(q, k, v):
+        f32 = [x.astype(jnp.float32).swapaxes(1, 2) for x in (q, k, v)]
+        return attention_ref(*f32, causal=True, scale=scale).swapaxes(1, 2)
+
+    ref = _value_and_grads(ref_attn, q, k, v, w)
+    for name, g, x, r in zip(("out", "dq", "dk", "dv"), got, xla, ref):
+        assert g.dtype == jnp.bfloat16, name
+        g, x, r = (np.asarray(a, np.float32) for a in (g, x, r))
+        for want in (x, r):
+            np.testing.assert_allclose(g, want, **_tol(jnp.bfloat16), err_msg=name)
+            assert np.linalg.norm(g - want) <= 1e-2 * np.linalg.norm(want), name
+
+
+def test_flash_attention_grads_are_block_independent():
+    """The backward kernels' tiling is a parameter, not a result: float32
+    gradients agree across block shapes that split the diagonal differently."""
+    ks = jax.random.split(jax.random.key(3), 4)
+    q = jax.random.normal(ks[0], (1, 4, 512, 64), jnp.float32)
+    k = jax.random.normal(ks[1], (1, 2, 512, 64), jnp.float32)
+    v = jax.random.normal(ks[2], (1, 2, 512, 64), jnp.float32)
+    w = jax.random.normal(ks[3], (1, 4, 512, 64), jnp.float32)
+
+    def grads(bq, bk):
+        return _value_and_grads(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, block_q=bq, block_k=bk, interpret=True), q, k, v, w)
+
+    base = grads(512, 512)
+    for bq, bk in [(128, 128), (128, 256), (256, 128)]:
+        for name, a, b in zip(("out", "dq", "dk", "dv"), grads(bq, bk), base):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-5,
+                                       err_msg=f"{name} block ({bq},{bk})")
+
+
+def _smollm_attention_args(s=512):
+    cfg = get_config("smollm-135m")
+    p = jax.eval_shape(lambda: {
+        "wq": jnp.zeros((cfg.d_model, cfg.num_heads, cfg.head_dim), jnp.float32),
+        "wk": jnp.zeros((cfg.d_model, cfg.num_kv_heads, cfg.head_dim), jnp.float32),
+        "wv": jnp.zeros((cfg.d_model, cfg.num_kv_heads, cfg.head_dim), jnp.float32),
+        "wo": jnp.zeros((cfg.num_heads, cfg.head_dim, cfg.d_model), jnp.float32)})
+    x = jax.ShapeDtypeStruct((1, s, cfg.d_model), jnp.bfloat16)
+    return cfg, p, x
+
+
+def test_attention_lowered_for_the_cpu_takes_the_xla_path():
+    """The training call fits the kernel, but the platform the step is
+    lowered for decides: on the CPU the XLA path, counted `attn.xla`."""
+    cfg, p, x = _smollm_attention_args()
+
+    def loss(p, x):
+        return L.attention(p, cfg, x, positions=None)[0].astype(jnp.float32).sum()
+
+    with trace.recording() as rec:
+        text = jax.jit(jax.grad(loss)).lower(p, x).as_text()
+    assert rec.counters.get("attn.xla", 0) > 0
+    assert rec.counters.get("attn.kernel", 0) == 0
+    assert "tpu_custom_call" not in text
+
+
+@pytest.mark.parametrize("case", ["train", "given_positions", "prefill", "decode", "cross",
+                                  "seq_not_whole_blocks", "head_dim_32", "mesh"])
+def test_kernel_fits_only_cacheless_causal_self_attention(case):
+    """What `_attn_core` hands the kernel where the lowering is for a TPU:
+    the training call, and none of the calls that must keep the XLA path."""
+    s = 384 if case == "seq_not_whole_blocks" else (1 if case == "decode" else 512)
+    d = 32 if case == "head_dim_32" else 64
+    q = jnp.zeros((1, s, 9, d), jnp.bfloat16)
+    k = jnp.zeros((1, 32 if case == "cross" else s, 3, d), jnp.bfloat16)
+    kwargs = dict(causal=case != "cross", q_offset=0, kv_valid_len=None)
+    if case == "given_positions":
+        kwargs["q_offset"] = jnp.asarray(0, jnp.int32)
+    if case in ("prefill", "decode"):
+        kwargs["kv_valid_len"] = jnp.asarray(s, jnp.int32)
+    rules = None
+    if case == "mesh":   # two mesh slots, whatever devices the process has
+        devices = np.array(jax.devices()[:1] * 2).reshape(2, 1)
+        rules = ShardingRules(jax.sharding.Mesh(devices, ("data", "model")), TRAIN_RULES)
+    with use_rules(rules):
+        assert L._kernel_fits(q, k, **kwargs) == (case == "train")
 
 
 # --------------------------------------------------------------------------- #

@@ -11,17 +11,22 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels import ops
+from repro.models import layers as L
 from repro.models import lm as LM
 from repro.models import make_model
+from repro.sharding.rules import TRAIN_RULES, ShardingRules, use_rules
 from repro.train import AdamWConfig, StepConfig, build_train_step, init_train_state
+from repro.utils import trace
 
 # One v5e chip's HBM (Google Cloud, "TPU v5e": 16 GB).
 HBM_BYTES = 16 * 10**9
@@ -70,36 +75,72 @@ def _assert_fits(compiled) -> None:
     assert used < HBM_BYTES, f"{used / 1e9:.2f} GB of arguments + temporaries"
 
 
+def _compile_train_step(one_chip, model, batch: int, seq: int):
+    """The cell's donating train step compiled for one chip, and what
+    `repro.utils.trace` counted while it was lowered."""
+    state = _on(one_chip, jax.eval_shape(
+        lambda: init_train_state(model, jax.random.key(0))))
+    tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=one_chip)
+    step = jax.jit(
+        build_train_step(model, AdamWConfig(total_steps=20, warmup_steps=1),
+                         StepConfig(q_chunk=512, loss_chunk=512)),
+        donate_argnums=(0,))
+    with trace.recording() as rec:
+        compiled = step.lower(state, {"inputs": tokens, "labels": tokens}).compile()
+    return compiled, rec.counters
+
+
+def _assert_attention_fused(compiled, counters, seq: int) -> None:
+    """Every attention call took the fused kernel: its custom calls are in
+    the program, and no fp32 score tile of a 512-row query chunk against
+    all `seq` keys is left under the `attn` scope."""
+    assert counters.get("attn.kernel", 0) > 0, counters
+    assert counters.get("attn.xla", 0) == 0, counters
+    text = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text
+    tile = re.compile(rf"f32\[(\d+,)*512,{seq}\]")
+    tiles = [line for line in text.splitlines() if "/attn/" in line and tile.search(line)]
+    assert not tiles, tiles[:2]
+
+
 @pytest.fixture(scope="module")
 def smollm():
     return make_model(get_config("smollm-135m"))
 
 
 def test_train_step_compiles_for_one_chip(one_chip, smollm):
-    state = _on(one_chip, jax.eval_shape(
-        lambda: init_train_state(smollm, jax.random.key(0))))
-    tokens = jax.ShapeDtypeStruct((TRAIN_BATCH, TRAIN_SEQ), jnp.int32,
-                                  sharding=one_chip)
-    step = jax.jit(
-        build_train_step(smollm, AdamWConfig(total_steps=20, warmup_steps=1),
-                         StepConfig(q_chunk=512, loss_chunk=512)),
-        donate_argnums=(0,))
-    _assert_fits(step.lower(state, {"inputs": tokens, "labels": tokens}).compile())
+    compiled, counters = _compile_train_step(one_chip, smollm, TRAIN_BATCH, TRAIN_SEQ)
+    _assert_fits(compiled)
+    _assert_attention_fused(compiled, counters, TRAIN_SEQ)
+
+
+def test_olmo_train_step_compiles_for_one_chip(one_chip):
+    """The OLMo cell's step: 8 of 16 layers at every published width, 4 x
+    2048, 16 MHA heads of 128 under a softmax scale that is no power of two."""
+    cfg = dataclasses.replace(get_config("olmo-1b"), num_layers=8)
+    compiled, counters = _compile_train_step(one_chip, make_model(cfg), 4, 2048)
+    _assert_fits(compiled)
+    _assert_attention_fused(compiled, counters, 2048)
 
 
 def test_prefill_and_decode_compile_for_one_chip(one_chip, smollm):
+    """Serving keeps the XLA attention: both programs hold a cache."""
     cfg = smollm.cfg
     params = _on(one_chip, jax.eval_shape(smollm.init, jax.random.key(0)))
     prompt = jax.ShapeDtypeStruct((SERVE_BATCH, PROMPT), jnp.int32, sharding=one_chip)
     prefill = jax.jit(lambda p, x: LM.lm_prefill(
         p, cfg, x, max_len=PROMPT + GEN, q_chunk=512))
-    _assert_fits(prefill.lower(params, prompt).compile())
+    with trace.recording() as rec:
+        _assert_fits(prefill.lower(params, prompt).compile())
+    assert rec.counters.get("attn.kernel", 0) == 0 < rec.counters.get("attn.xla", 0)
 
     caches = _on(one_chip, jax.eval_shape(
         lambda: LM.make_stack_cache(cfg, SERVE_BATCH, PROMPT + GEN)))
     ids = jax.ShapeDtypeStruct((SERVE_BATCH, 1), jnp.int32, sharding=one_chip)
     pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
-    _assert_fits(jax.jit(smollm.decode_step).lower(params, ids, caches, pos).compile())
+    with trace.recording() as rec:
+        _assert_fits(jax.jit(smollm.decode_step).lower(params, ids, caches, pos).compile())
+    assert rec.counters.get("attn.kernel", 0) == 0 < rec.counters.get("attn.xla", 0)
 
 
 def test_granite_train_step_fills_one_chip(one_chip):
@@ -109,18 +150,39 @@ def test_granite_train_step_fills_one_chip(one_chip):
     cell must use at least three quarters of the chip."""
     cfg = dataclasses.replace(get_config("granite-4.0-h-micro"), num_layers=10,
                               vocab_size=12544)
-    model = make_model(cfg)
-    state = _on(one_chip, jax.eval_shape(
-        lambda: init_train_state(model, jax.random.key(0))))
-    tokens = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip)
-    step = jax.jit(
-        build_train_step(model, AdamWConfig(total_steps=20, warmup_steps=1),
-                         StepConfig(q_chunk=512, loss_chunk=512)),
-        donate_argnums=(0,))
-    compiled = step.lower(state, {"inputs": tokens, "labels": tokens}).compile()
+    compiled, counters = _compile_train_step(one_chip, make_model(cfg), 1, 8192)
     _assert_fits(compiled)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes >= 0.75 * HBM_BYTES
+    _assert_attention_fused(compiled, counters, 8192)
+
+
+@pytest.mark.parametrize("case", ["train", "cross", "seq_not_whole_blocks", "mesh"])
+def test_attention_path_lowered_for_the_chip(topo, one_chip, case):
+    """SmolLM's attention lowered for the chip: the cache-less causal call
+    takes the kernel; cross-attention, a sequence that is no whole number
+    of kernel blocks, and a mesh of four chips keep the XLA path."""
+    cfg = get_config("smollm-135m")
+    s = 384 if case == "seq_not_whole_blocks" else 512
+    sharding, rules = one_chip, None
+    if case == "mesh":
+        mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+        sharding, rules = NamedSharding(mesh, PartitionSpec()), ShardingRules(mesh, TRAIN_RULES)
+    p = {name: jax.ShapeDtypeStruct(spec.shape, spec.dtype, sharding=sharding)
+         for name, spec in L.attention_spec(cfg).items()}
+    x = jax.ShapeDtypeStruct((4, s, cfg.d_model), jnp.bfloat16, sharding=sharding)
+    src = x if case == "cross" else None
+
+    def loss(p, x, src):
+        y, _ = L.attention(p, cfg, x, positions=None, kv_source=src)
+        return y.astype(jnp.float32).sum()
+
+    with use_rules(rules), trace.recording() as rec:
+        text = jax.jit(jax.grad(loss)).lower(p, x, src).as_text()
+    took_kernel = case == "train"
+    assert rec.counters.get("attn.kernel", 0) == (1 if took_kernel else 0), rec.counters
+    assert rec.counters.get("attn.xla", 0) == (0 if took_kernel else 1), rec.counters
+    assert ("tpu_custom_call" in text) == took_kernel
 
 
 def test_flash_attention_lowers_to_a_kernel(one_chip):
