@@ -91,7 +91,6 @@ def run_one_chip(train_sizes: list[str], serve_sizes: list[str]) -> None:
 
     from repro.ckpt import restore_checkpoint
     from repro.configs import get_config
-    from repro.io import IOPolicy
     from repro.launch import serve, train
 
     emit("link", latency_ms=LINK_LATENCY_MS, bw_mbps=LINK_BW_MBPS)
@@ -106,10 +105,7 @@ def run_one_chip(train_sizes: list[str], serve_sizes: list[str]) -> None:
          state_bytes=state_bytes)
 
     t0 = time.perf_counter()
-    restored, manifest = restore_checkpoint(
-        ckpt_uri, "ckpt", run.state,
-        policy=IOPolicy(engine="rolling", depth=2, eviction_interval_s=0.2),
-    )
+    restored, manifest = restore_checkpoint(ckpt_uri, "ckpt", run.state)
     jax.block_until_ready(restored)
     restore_s = time.perf_counter() - t0
     live, back = jax.tree.leaves(run.state), jax.tree.leaves(restored)
@@ -186,8 +182,7 @@ def run_elastic(reduced: bool = False) -> None:
     host_id, num_hosts = mesh_host_shard()
     t0 = time.perf_counter()
     restored, _ = restore_resharded(
-        uri, "elastic", template, host_id=host_id, num_hosts=num_hosts,
-        policy=IOPolicy(engine="rolling", depth=2, eviction_interval_s=0.2))
+        uri, "elastic", template, host_id=host_id, num_hosts=num_hosts)
     jax.block_until_ready(restored)
     restore_s = time.perf_counter() - t0
 

@@ -60,11 +60,7 @@ def main() -> None:
         params, shardings_b,
     )
     with mesh_b:
-        restored, _ = restore_checkpoint(
-            store, "elastic", template,
-            policy=IOPolicy(engine="rolling", depth=2,
-                            eviction_interval_s=0.2),
-        )
+        restored, _ = restore_checkpoint(store, "elastic", template)
 
     # --- verify bit-identical logical arrays, new physical layout --------------
     for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(restored)):

@@ -22,12 +22,13 @@ facade. The stream's layout comes from the manifest alone: each leaf's
 size is its entry's ``prod(shape) * itemsize``, the bytes `save_checkpoint`
 wrote, so a restore asks the store for no sizes. Its only metadata
 requests are the manifest GET and, without a ``step``, the LIST.
-`policy=IOPolicy(engine="rolling")` (the default) runs the three-thread
+The default policy, `RESTORE_POLICY`, runs the three-thread rolling
 engine, so fetching leaf k+1..k+d from the store overlaps with
 deserializing + `device_put`-ing leaf k — the paper's max(T_cloud, T_comp)
-pipeline applied to checkpoint load.
+pipeline applied to checkpoint load. `ServeEngine.from_store` restores
+with the same default.
 `engine="sequential"` is the S3Fs-style baseline the benchmarks A/B
-against. The legacy `mode=` string kwarg still works and warns.
+against.
 
 Elastic: the restore template's shardings may come from a different mesh
 than save time; `device_put` reshards each leaf onto the new topology.
@@ -48,7 +49,6 @@ import math
 import re
 import threading
 import time
-import warnings
 from dataclasses import dataclass
 
 import jax
@@ -76,6 +76,11 @@ META_RETRY = RetryPolicy(max_retries=4, backoff_s=0.02, backoff_cap_s=1.0)
 # — a fresh instance per metadata op would silently degrade a policy
 # budget to a per-call cap.
 _META_RETRIER = Retrier(META_RETRY)
+
+# The restore's reader when the caller passes no policy: the rolling engine
+# two 8 MiB blocks ahead of the consumer.
+RESTORE_POLICY = IOPolicy(engine="rolling", blocksize=8 << 20, depth=2,
+                          eviction_interval_s=0.2)
 
 
 def _with_retries(fn, *, policy: RetryPolicy = META_RETRY):
@@ -227,12 +232,9 @@ def restore_checkpoint(
     *,
     step: int | None = None,
     policy: IOPolicy | None = None,
-    mode: str | None = None,
     tiers: list[CacheTier] | None = None,
     cache_dir: str | None = None,
     cache_capacity: int | None = None,
-    blocksize: int = 8 << 20,
-    prefetch_depth: int = 2,
     shard: tuple[int, int] | None = None,
 ):
     """Restore into the structure (and shardings, if any) of `template`.
@@ -240,9 +242,7 @@ def restore_checkpoint(
     ShapeDtypeStructs (with or without shardings).
 
     Leaf bytes stream through `PrefetchFS`; pass ``policy`` to select the
-    reader engine and its knobs. ``mode``/``blocksize``/``prefetch_depth``
-    are the deprecated pre-facade spelling and are folded into a policy
-    when no explicit ``policy`` is given.
+    reader engine and its knobs (default `RESTORE_POLICY`).
 
     ``cache_dir`` makes the restore crash-warm: leaf blocks cache in a
     persistent journaled `DirTier` under that directory and stay resident
@@ -266,34 +266,21 @@ def restore_checkpoint(
     with trace.span("restore"):
         trace.count("restore.count")
         store = open_store(store)
+        if policy is None:
+            policy = RESTORE_POLICY
         warm_cache = cache_dir is not None and tiers is None
         if warm_cache:
             from repro.store.tiers import DirTier
 
             cap = cache_capacity
             if cap is None:
-                bs = policy.blocksize if policy is not None else blocksize
-                cap = max(4 * bs, 256 << 20)
+                cap = max(4 * policy.blocksize, 256 << 20)
             tiers = [DirTier(cap, root=cache_dir, name="ckpt.cache")]
         # Everything past tier construction runs under the finally that
         # releases the cache root's advisory lock — a missing manifest or a
         # failed metadata call must not leak the lock in a long-lived process
         # (the retry's DirTier would silently become a non-owner).
         try:
-            if mode is not None:
-                warnings.warn(
-                    "restore_checkpoint(mode=...) is deprecated; pass "
-                    "policy=IOPolicy(engine=...) instead",
-                    DeprecationWarning,
-                    stacklevel=2,
-                )
-            if policy is None:
-                policy = IOPolicy(
-                    engine=mode or "rolling",
-                    blocksize=blocksize,
-                    depth=prefetch_depth,
-                    eviction_interval_s=0.2,
-                )
             if policy.io_class == "default":
                 # Restore streams are the checkpoint workload class (top-tier
                 # HSM admission); an explicit io_class — e.g. "serve" from

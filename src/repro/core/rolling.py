@@ -50,13 +50,13 @@ from __future__ import annotations
 import enum
 import threading
 import time
-import warnings
 from dataclasses import dataclass, field
 
 from repro.core.autotune import AimdDepthController, BlockSizeTuner
 from repro.core.plan import Block, BlockPlan
 from repro.io.integrity import check_block
-from repro.io.retry import Hedger, Retrier, RetryPolicy
+from repro.io.policy import IOPolicy
+from repro.io.retry import Hedger, Retrier
 from repro.store.base import (
     IntegrityError,
     ObjectMeta,
@@ -68,6 +68,10 @@ from repro.store.tiers import BlockMeta, CacheFlight, CacheIndex, CacheTier
 from repro.utils import get_logger
 
 log = get_logger("core.rolling")
+
+# Share of a tier's capacity past which a consumed block wakes the eviction
+# thread at once instead of leaving it to the periodic interval.
+HIGH_WATER = 0.75
 
 
 class BlockState(enum.Enum):
@@ -154,63 +158,21 @@ class RollingPrefetcher:
         store: ObjectStore,
         files: list[ObjectMeta],
         tiers: list[CacheTier],
-        blocksize: int,
+        policy: IOPolicy,
         *,
-        depth: int = 1,
-        max_depth: int | None = None,
-        coalesce: int = 1,
-        readahead_blocks: int | None = None,
-        eviction_interval_s: float = 5.0,
-        high_water: float = 0.75,
-        max_retries: int = 3,
-        retry_backoff_s: float = 0.05,
-        retry: RetryPolicy | None = None,
-        hedge_timeout_s: float | None = None,
-        max_hedges: int = 4,
-        throttle_aimd: bool = True,
         tuner: BlockSizeTuner | None = None,
         index: CacheIndex | None = None,
-        io_class: str = "default",
-        verify: str = "edges",
     ) -> None:
         if not tiers:
             raise ValueError("at least one cache tier is required")
-        if verify not in ("off", "edges", "full"):
-            raise ValueError(
-                f"verify must be 'off', 'edges', or 'full', got {verify!r}"
-            )
-        if depth < 1:
-            raise ValueError(f"depth must be >= 1, got {depth}")
-        if max_depth is not None and max_depth < depth:
-            raise ValueError(
-                f"max_depth ({max_depth}) must be >= depth ({depth})"
-            )
-        if coalesce < 1:
-            raise ValueError(f"coalesce must be >= 1, got {coalesce}")
-        if readahead_blocks is not None and readahead_blocks < 1:
-            raise ValueError(
-                f"readahead_blocks must be >= 1, got {readahead_blocks}"
-            )
+        self.policy = policy
         self.store = store
-        self.plan = BlockPlan(files, blocksize)
+        self.plan = BlockPlan(files, policy.blocksize)
         self.tiers = tiers
-        self.depth = depth
-        self.coalesce = coalesce
-        self.readahead_blocks = readahead_blocks
-        self.eviction_interval_s = eviction_interval_s
-        self.high_water = high_water
-        self.hedge_timeout_s = hedge_timeout_s
+        # An unset coalesce fetches block-at-a-time; `PrefetchFS`'s
+        # autotune opens the ceiling before the engine sees the policy.
+        self.coalesce = policy.coalesce if policy.coalesce is not None else 1
         self.tuner = tuner
-        # Unified resilience layer: ONE Retrier (shared jitter rng and
-        # retry budget across all prefetch streams + the reader's direct
-        # GETs) and ONE Hedger (the max-hedges-in-flight cap bounds
-        # duplicates across concurrent streams). ThrottleError responses
-        # reach `_on_throttle`, which shrinks the AIMD stream target —
-        # backend pushback lowers prefetch concurrency, not just this
-        # request's schedule.
-        self.retry = (retry if retry is not None else RetryPolicy(
-            max_retries=max_retries, backoff_s=retry_backoff_s))
-        self.throttle_aimd = throttle_aimd
         # Shared cache index: residency + refcounts + single-flight fetch
         # registration. When the caller (PrefetchFS) supplies one, every
         # reader over these tiers shares it — N readers of the same key
@@ -222,35 +184,43 @@ class RollingPrefetcher:
         # Workload class stamped on every acquire/reserve: the HSM index
         # keys admission (entry tier, protection, scan resistance) and
         # per-class hit accounting off it; a flat index ignores it.
-        self.io_class = io_class
+        self.io_class = policy.io_class
         # End-to-end integrity posture: "off" never hashes, "edges" mints
         # a digest at the store fetch and re-checks at tier boundaries
         # (trusting self-verifying tiers), "full" re-checks every cached
         # read. See `repro.io.integrity`.
-        self.verify = verify
+        self.verify = policy.verify
         self.stats = PrefetchStats()
         self._aimd = (
-            AimdDepthController(depth, max_depth)
-            if max_depth is not None else None
+            AimdDepthController(policy.depth, policy.max_depth)
+            if policy.max_depth is not None else None
         )
+        # Unified resilience layer: ONE Retrier (shared jitter rng and
+        # retry budget across all prefetch streams + the reader's direct
+        # GETs) and ONE Hedger (the max-hedges-in-flight cap bounds
+        # duplicates across concurrent streams). ThrottleError responses
+        # reach `_on_throttle`, which shrinks the AIMD stream target —
+        # backend pushback lowers prefetch concurrency, not just this
+        # request's schedule.
         self._retrier = Retrier(
-            self.retry,
+            policy.retry,
             on_retry=lambda attempt, exc, pause: self.stats.bump(retries=1),
             on_throttle=self._on_throttle,
         )
         self._hedger = Hedger(
-            hedge_timeout_s,
-            max_in_flight=max_hedges,
+            policy.hedge_timeout_s,
+            max_in_flight=policy.max_hedges,
             on_hedge=lambda: self.stats.bump(hedges=1),
         )
-        self._streams = max_depth if max_depth is not None else depth
+        self._streams = (policy.max_depth if policy.max_depth is not None
+                         else policy.depth)
         self._spawned = 0             # streams actually started (lazy)
 
         self._info: list[_BlockInfo] = [_BlockInfo() for _ in self.plan.blocks]
         self._cond = threading.Condition()
         self._next_block = 0          # lowest block index not yet claimed
         self._reader_block = 0        # reader position, in block indexes
-        self._target_depth = depth    # streams allowed to fetch right now
+        self._target_depth = policy.depth  # streams allowed to fetch right now
         self._probe_width = 0         # width alternator while tuner is cold
         self._fetch = True            # the paper's shared `fetch` flag
         self._threads: list[threading.Thread] = []
@@ -377,8 +347,8 @@ class RollingPrefetcher:
                     return None  # plan fully claimed -> stream terminates
                 idx = self._next_block
                 limit = None
-                if self.readahead_blocks is not None:
-                    limit = self._reader_block + self.readahead_blocks
+                if self.policy.readahead_blocks is not None:
+                    limit = self._reader_block + self.policy.readahead_blocks
                     if idx >= limit:
                         # Horizon exhausted; reader progress notifies.
                         self._cond.wait(timeout=0.5)
@@ -671,7 +641,7 @@ class RollingPrefetcher:
         streams hammering a rate-limited backend; shrinking concurrency
         is what actually relieves the pressure."""
         self.stats.bump(throttles=1)
-        if self._aimd is None or not self.throttle_aimd:
+        if self._aimd is None or not self.policy.throttle_aimd:
             return
         new = self._aimd.on_throttle()
         self.stats.note_depth(new)
@@ -969,7 +939,7 @@ class RollingPrefetcher:
                 # tier must not stall prefetchers for up to the whole
                 # eviction interval.
                 if (tier is not None
-                        and tier.used >= self.high_water * tier.capacity):
+                        and tier.used >= HIGH_WATER * tier.capacity):
                     notify_evict = True
             self._cond.notify_all()
         if notify_evict:
@@ -1015,7 +985,7 @@ class RollingPrefetcher:
         while True:
             with self._evict_cond:
                 if self._fetch and not self._evict_wanted:
-                    self._evict_cond.wait(timeout=self.eviction_interval_s)
+                    self._evict_cond.wait(timeout=self.policy.eviction_interval_s)
                 self._evict_wanted = False
             if not self._fetch:
                 return
@@ -1055,28 +1025,6 @@ class RollingPrefetchFile:
         self._pos = 0
         self._closed = False
         prefetcher.start()
-
-    # Deprecated constructor: forwards to the PrefetchFS reader registry.
-    @classmethod
-    def open(
-        cls,
-        store: ObjectStore,
-        files: list[ObjectMeta],
-        tiers: list[CacheTier],
-        blocksize: int,
-        **kw,
-    ) -> "RollingPrefetchFile":
-        warnings.warn(
-            "RollingPrefetchFile.open(...) is deprecated; use "
-            "repro.io.PrefetchFS(store, policy=IOPolicy(engine='rolling', "
-            "...)).open_many(files) instead",
-            DeprecationWarning,
-            stacklevel=2,
-        )
-        from repro.io import IOPolicy, PrefetchFS
-
-        policy = IOPolicy(engine="rolling", blocksize=blocksize, **kw)
-        return PrefetchFS(store, policy=policy, tiers=tiers).open_many(files)
 
     @property
     def size(self) -> int:

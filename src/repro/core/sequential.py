@@ -23,7 +23,8 @@ from typing import TYPE_CHECKING
 
 from repro.core.plan import Block, BlockPlan
 from repro.io.integrity import check_block
-from repro.io.retry import Retrier, RetryPolicy
+from repro.io.policy import IOPolicy
+from repro.io.retry import Retrier
 from repro.store.base import (
     IntegrityError,
     ObjectMeta,
@@ -70,25 +71,18 @@ class SequentialFile:
         self,
         store: ObjectStore,
         files: list[ObjectMeta],
-        blocksize: int,
-        cache_blocks: int = 1,
+        policy: IOPolicy,
+        *,
         tuner: "BlockSizeTuner | None" = None,
         index: CacheIndex | None = None,
-        retry: RetryPolicy | None = None,
-        io_class: str = "default",
-        verify: str = "edges",
     ) -> None:
-        if verify not in ("off", "edges", "full"):
-            raise ValueError(
-                f"verify must be 'off', 'edges', or 'full', got {verify!r}"
-            )
         self.store = store
-        self.plan = BlockPlan(files, blocksize)
-        self.cache_blocks = max(1, cache_blocks)
+        self.plan = BlockPlan(files, policy.blocksize)
+        self.cache_blocks = max(1, policy.cache_blocks)
         self.tuner = tuner
         self.index = index
-        self.io_class = io_class
-        self.verify = verify
+        self.io_class = policy.io_class
+        self.verify = policy.verify
         self.stats = SequentialStats()
         # Pre-resilience-layer this engine retried NOTHING: the first
         # transient fault of a direct read or a `_join_flight` fallback
@@ -96,9 +90,8 @@ class SequentialFile:
         # rode out the same schedule. Every store request now resolves
         # through the shared Retrier (full-jitter backoff), so both
         # engines survive the same faults.
-        self.retry = retry if retry is not None else RetryPolicy()
         self._retrier = Retrier(
-            self.retry,
+            policy.retry,
             on_retry=self._on_retry,
             on_throttle=self._on_throttle,
         )

@@ -20,7 +20,6 @@ from __future__ import annotations
 
 import queue
 import threading
-import warnings
 from dataclasses import dataclass
 
 import jax
@@ -39,19 +38,8 @@ log = get_logger("data.loader")
 class LoaderConfig:
     seq_len: int
     batch_size: int              # per-host batch
-    mode: str | None = None      # DEPRECATED: use policy=IOPolicy(engine=...)
-    blocksize: int = 8 << 20
-    depth: int = 2               # device-feed pipeline depth
     host_id: int = 0
     num_hosts: int = 1
-    hedge_timeout_s: float | None = None
-    prefetch_depth: int = 1      # concurrent fetch streams (beyond paper)
-    eviction_interval_s: float = 0.2
-    autotune: bool = False
-    # Epoch-to-epoch cache reuse: consumed blocks stay resident in the
-    # tiers (LRU under capacity pressure) so the per-epoch stream reopen
-    # starts warm — with a persistent DirTier, so does a restarted job.
-    keep_cached: bool = False
     # Partition the file list per host (host h streams files h::H). Set
     # False when every host must see the FULL dataset in the same order
     # (e.g. evaluation sweeps, or data-parallel recipes that shard at the
@@ -59,31 +47,9 @@ class LoaderConfig:
     # become N-fold WAN traffic — each block's home host performs the one
     # backing GET and siblings pull it over the LAN.
     shard_files: bool = True
-    policy: IOPolicy | None = None   # reader policy (preferred over mode/...)
-
-    def reader_policy(self) -> IOPolicy:
-        """Effective `IOPolicy`: `policy` wins; otherwise one is assembled
-        from the legacy per-field knobs (with a deprecation warning when the
-        legacy `mode` string was passed)."""
-        if self.mode is not None:
-            # stacklevel 3: reader_policy <- loader __init__ <- user code.
-            warnings.warn(
-                "LoaderConfig(mode=...) is deprecated; pass "
-                "policy=IOPolicy(engine=...) instead",
-                DeprecationWarning,
-                stacklevel=3,
-            )
-        if self.policy is not None:
-            return self.policy
-        return IOPolicy(
-            engine=self.mode or "rolling",
-            blocksize=self.blocksize,
-            depth=self.prefetch_depth,
-            eviction_interval_s=self.eviction_interval_s,
-            hedge_timeout_s=self.hedge_timeout_s,
-            autotune=self.autotune,
-            keep_cached=self.keep_cached,
-        )
+    # The reader's configuration: engine, block size, prefetch depth,
+    # autotune, epoch-to-epoch cache reuse (`keep_cached`), ...
+    policy: IOPolicy = IOPolicy(eviction_interval_s=0.2)
 
 
 @dataclass
@@ -119,11 +85,7 @@ class PrefetchingDataLoader:
         if not self.my_files:
             raise ValueError(f"host {cfg.host_id}: no files assigned")
         self.cursor = cursor or DataCursor()
-        policy = cfg.reader_policy()
-        if cfg.autotune and not policy.autotune:
-            policy = policy.replace(autotune=True)
-        if cfg.keep_cached and not policy.keep_cached:
-            policy = policy.replace(keep_cached=True)
+        policy = cfg.policy
         if policy.io_class == "default":
             # Epoch sweeps are the canonical bulk-scan class: under an HSM
             # hierarchy they enter at the disk level and are

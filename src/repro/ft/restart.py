@@ -52,17 +52,16 @@ class RestartManager:
     def resume_point(self) -> int | None:
         return latest_step(self.store, self.prefix)
 
-    def restore(self, template, *, policy: IOPolicy | None = None,
-                mode: str | None = None):
+    def restore(self, template, *, policy: IOPolicy | None = None):
         """Returns (state, step, cursor) or None if no checkpoint exists.
-        ``policy`` selects the restore reader engine (default rolling);
-        ``mode`` is the deprecated string spelling."""
+        ``policy`` selects the restore reader engine (default
+        `RESTORE_POLICY`)."""
         step = self.resume_point()
         if step is None:
             return None
         state, manifest = restore_checkpoint(
             self.store, self.prefix, template, step=step,
-            policy=policy, mode=mode,
+            policy=policy,
             cache_dir=self.cache_dir, cache_capacity=self.cache_capacity,
         )
         cursor = DataCursor.from_dict(

@@ -6,8 +6,10 @@
   * ``direct``     — uncached pass-through range reads.
 
 Each factory receives ``(store, files, tiers, policy)`` and returns a
-`Reader`. New engines (real S3, async, sharded multi-host) register the
-same way and become reachable from every `PrefetchFS` call site.
+`Reader`; the rolling and sequential engines take the `IOPolicy` whole and
+read their own fields from it. New engines (real S3, async, sharded
+multi-host) register the same way and become reachable from every
+`PrefetchFS` call site.
 
 The core engine modules are imported lazily inside the factories: they
 depend on ``repro.io.retry`` (the unified resilience layer), and a
@@ -36,24 +38,8 @@ def open_rolling(store: ObjectStore, files: list[ObjectMeta],
                  index: CacheIndex | None = None):
     from repro.core.rolling import RollingPrefetcher, RollingPrefetchFile
 
-    return RollingPrefetchFile(
-        RollingPrefetcher(
-            store, files, tiers, policy.blocksize,
-            depth=policy.depth,
-            max_depth=policy.max_depth,
-            coalesce=policy.coalesce if policy.coalesce is not None else 1,
-            readahead_blocks=policy.readahead_blocks,
-            eviction_interval_s=policy.eviction_interval_s,
-            retry=policy.retry_policy(),
-            hedge_timeout_s=policy.hedge_timeout_s,
-            max_hedges=policy.max_hedges,
-            throttle_aimd=policy.throttle_aimd,
-            tuner=tuner,
-            index=index,
-            io_class=policy.io_class,
-            verify=policy.verify,
-        )
-    )
+    return RollingPrefetchFile(RollingPrefetcher(store, files, tiers, policy,
+                                                 tuner=tuner, index=index))
 
 
 @register_reader("sequential", accepts_tuner=True, accepts_index=True)
@@ -63,10 +49,7 @@ def open_sequential(store: ObjectStore, files: list[ObjectMeta],
                     index: CacheIndex | None = None):
     from repro.core.sequential import SequentialFile
 
-    return SequentialFile(store, files, policy.blocksize,
-                          cache_blocks=policy.cache_blocks, tuner=tuner,
-                          index=index, retry=policy.retry_policy(),
-                          io_class=policy.io_class, verify=policy.verify)
+    return SequentialFile(store, files, policy, tuner=tuner, index=index)
 
 
 @register_reader("direct")

@@ -4,16 +4,17 @@ The paper's extension of S3Fs keeps prefetch configuration out of the
 application: callers open files and the filesystem carries the policy
 (block size, cache tiers, concurrency). `IOPolicy` plays that role here —
 one frozen value object covering every knob any engine understands, built
-from keyword arguments, another config object (`from_config`), or an
-existing policy plus per-open overrides (`replace`). Engines read only the
-fields they care about; unknown-engine validation happens in the registry.
+from keyword arguments or from an existing policy plus per-open overrides
+(`replace`). It is the only place a reader's configuration is spelled and
+checked: the engines take it whole and read only the fields they care
+about; unknown-engine validation happens in the registry.
 """
 
 from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass
-from typing import Any, Mapping
+from typing import Any
 
 from repro.io.retry import RetryPolicy
 
@@ -24,14 +25,14 @@ class IOPolicy:
 
     Fields consumed per engine:
       * ``rolling``    — blocksize, depth, max_depth, coalesce,
-        readahead_blocks, eviction_interval_s, retry (or
-        max_retries/retry_backoff_s), hedge_timeout_s, max_hedges,
-        throttle_aimd, autotune, tier_capacity;
-      * ``sequential`` — blocksize, cache_blocks, retry;
+        readahead_blocks, eviction_interval_s, retry, hedge_timeout_s,
+        max_hedges, throttle_aimd, io_class, verify, autotune,
+        tier_capacity;
+      * ``sequential`` — blocksize, cache_blocks, retry, io_class, verify;
       * ``direct``     — none (pass-through range reads);
       * write-behind `Writer` (``PrefetchFS.open_write``) — blocksize (the
-        part size), write_depth, retry (or max_retries/retry_backoff_s),
-        hedge_timeout_s, max_hedges, tier_capacity (staging budget).
+        part size), write_depth, retry, hedge_timeout_s, max_hedges,
+        tier_capacity (staging budget).
 
     The adaptive-scheduling knobs:
       * ``coalesce`` — max adjacent blocks one store request may carry;
@@ -57,13 +58,9 @@ class IOPolicy:
     readahead_blocks: int | None = None  # fetch horizon ahead of the reader
     write_depth: int = 2                # concurrent write-behind part uploads
     eviction_interval_s: float = 5.0
-    max_retries: int = 3
-    retry_backoff_s: float = 0.05
-    # The full resilience configuration. None (the default) builds a
-    # `RetryPolicy` from the legacy `max_retries`/`retry_backoff_s`
-    # knobs (full-jitter backoff); pass an explicit `RetryPolicy` for
-    # the budget/deadline/jitter knobs. See :meth:`retry_policy`.
-    retry: RetryPolicy | None = None
+    # The resilience configuration every store request of the handle
+    # resolves through (full-jitter backoff by default).
+    retry: RetryPolicy = RetryPolicy()
     hedge_timeout_s: float | None = None
     max_hedges: int = 4                 # hedge duplicates in flight, per handle
     # Throttle→depth feedback: a `ThrottleError` from the store halves
@@ -121,8 +118,6 @@ class IOPolicy:
             raise ValueError(
                 f"write_depth must be >= 1, got {self.write_depth}"
             )
-        if self.max_retries < 0:
-            raise ValueError(f"max_retries must be >= 0, got {self.max_retries}")
         if self.max_hedges < 1:
             raise ValueError(f"max_hedges must be >= 1, got {self.max_hedges}")
         if not self.io_class or not isinstance(self.io_class, str):
@@ -134,37 +129,9 @@ class IOPolicy:
                 f"verify must be 'off', 'edges', or 'full', got {self.verify!r}"
             )
 
-    def retry_policy(self) -> RetryPolicy:
-        """The effective `RetryPolicy`: the explicit ``retry`` object
-        when given, else one built from the legacy scalar knobs (with
-        full-jitter backoff — the unjittered ``2 ** attempt`` loops this
-        replaces synchronized concurrent streams into retry storms)."""
-        if self.retry is not None:
-            return self.retry
-        return RetryPolicy(max_retries=self.max_retries,
-                           backoff_s=self.retry_backoff_s)
-
     def replace(self, **overrides: Any) -> "IOPolicy":
         """A copy with the given fields overridden (per-open tweaks)."""
         return dataclasses.replace(self, **overrides)
-
-    @classmethod
-    def from_config(cls, src: Mapping[str, Any] | Any = None,
-                    **overrides: Any) -> "IOPolicy":
-        """Build a policy from a mapping or any object whose attribute
-        names match `IOPolicy` field names exactly; unknown keys are
-        ignored, explicit keyword overrides win. Configs with their own
-        reader-knob spellings need an explicit mapping instead (e.g.
-        `LoaderConfig.reader_policy()` maps `prefetch_depth` -> `depth`)."""
-        names = {f.name for f in dataclasses.fields(cls)}
-        kw: dict[str, Any] = {}
-        if src is not None:
-            if isinstance(src, Mapping):
-                kw.update((k, v) for k, v in src.items() if k in names)
-            else:
-                kw.update((n, getattr(src, n)) for n in names if hasattr(src, n))
-        kw.update(overrides)
-        return cls(**kw)
 
     def default_tier_capacity(self) -> int:
         """Cache budget used when the filesystem builds its own tier: at

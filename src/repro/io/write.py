@@ -208,7 +208,7 @@ class Writer:
         # Hedger (max-hedges-in-flight cap) replace the old inline
         # `2 ** attempt` loop and its copy-pasted hedging.
         self._retrier = Retrier(
-            policy.retry_policy(),
+            policy.retry,
             on_retry=lambda attempt, exc, pause: self.stats.bump(retries=1),
             on_throttle=lambda: self.stats.bump(throttles=1),
         )

@@ -75,23 +75,18 @@ class ServeEngine:
                    policy=None, step: int | None = None, max_batch: int = 8,
                    pad_id: int = 0) -> "ServeEngine":
         """Cold-start an engine from checkpointed weights in an object
-        store, streamed through the `PrefetchFS` facade: pass
-        ``policy=IOPolicy(engine="rolling", depth=...)`` to overlap leaf
-        fetches with `device_put` (serving cold-start is the paper's
-        sequential multi-object stream)."""
-        from repro.ckpt.manager import restore_checkpoint
-        from repro.io import IOPolicy
+        store, streamed through the `PrefetchFS` facade with the restore's
+        own default policy (`RESTORE_POLICY`) unless ``policy`` is given:
+        leaf fetches overlap `device_put` (serving cold-start is the
+        paper's sequential multi-object stream)."""
+        from repro.ckpt.manager import RESTORE_POLICY, restore_checkpoint
 
         # Serving cold-start is the latency-critical restore class: under
         # an HSM hierarchy its blocks admit into (and are protected in)
         # the top tier, so a concurrent bulk scan cannot flush the weights
-        # a replica re-reads on every restart.
-        if policy is None:
-            # Mirrors restore_checkpoint's own default policy, plus the
-            # serve class.
-            policy = IOPolicy(engine="rolling", blocksize=8 << 20, depth=2,
-                              eviction_interval_s=0.2, io_class="serve")
-        elif policy.io_class == "default":
+        # a replica re-reads on every restart. An explicit class wins.
+        policy = policy if policy is not None else RESTORE_POLICY
+        if policy.io_class == "default":
             policy = policy.replace(io_class="serve")
         params, _ = restore_checkpoint(store, prefix, template, step=step,
                                        policy=policy)

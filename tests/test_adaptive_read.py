@@ -12,7 +12,7 @@ from repro.core import cost_model
 from repro.core.autotune import AimdDepthController, BlockSizeTuner
 from repro.core.rolling import BlockState, RollingPrefetcher
 from repro.core.sequential import SequentialFile
-from repro.io import IOPolicy, PrefetchFS
+from repro.io import IOPolicy, PrefetchFS, RetryPolicy
 from repro.store import DirStore, LinkModel, MemStore, MemTier, SimS3Store
 from repro.store.base import ObjectMeta, StoreError, adjacent_runs
 
@@ -110,8 +110,8 @@ class TestCoalescedPrefetch:
         objects = {f"f{i}": payload(4096, seed=i) for i in range(3)}
         store = make_store(objects)
         with RollingPrefetcher(
-            store, metas(store), [MemTier(64 << 10)], 512,
-            coalesce=8, eviction_interval_s=0.01,
+            store, metas(store), [MemTier(64 << 10)],
+            policy=IOPolicy(blocksize=512, coalesce=8, eviction_interval_s=0.01),
         ) as pf:
             got = pf.read_range(0, pf.plan.total_bytes)
         assert got == b"".join(objects[m.key] for m in metas(store))
@@ -126,8 +126,8 @@ class TestCoalescedPrefetch:
         objects = {f"f{i}": payload(700 + i * 13, seed=i) for i in range(6)}
         store = make_store(objects)
         with RollingPrefetcher(
-            store, metas(store), [MemTier(64 << 10)], 256,
-            coalesce=16, eviction_interval_s=0.01,
+            store, metas(store), [MemTier(64 << 10)],
+            policy=IOPolicy(blocksize=256, coalesce=16, eviction_interval_s=0.01),
         ) as pf:
             got = pf.read_range(0, pf.plan.total_bytes)
         assert got == b"".join(objects[m.key] for m in metas(store))
@@ -137,9 +137,9 @@ class TestCoalescedPrefetch:
         store = make_store(objects)
         store.link.fail_next(3)
         with RollingPrefetcher(
-            store, metas(store), [MemTier(32 << 10)], 512,
-            coalesce=4, max_retries=6, retry_backoff_s=0.001,
-            eviction_interval_s=0.01,
+            store, metas(store), [MemTier(32 << 10)],
+            policy=IOPolicy(blocksize=512, coalesce=4, eviction_interval_s=0.01,
+                            retry=RetryPolicy(max_retries=6, backoff_s=0.001)),
         ) as pf:
             assert pf.read_range(0, 8192) == payload(8192)
         assert pf.stats.retries >= 3
@@ -148,8 +148,9 @@ class TestCoalescedPrefetch:
         objects = {"a": payload(16384)}
         store = make_store(objects, latency=0.05)
         with RollingPrefetcher(
-            store, metas(store), [MemTier(64 << 10)], 2048,
-            coalesce=4, hedge_timeout_s=0.01, eviction_interval_s=0.01,
+            store, metas(store), [MemTier(64 << 10)],
+            policy=IOPolicy(blocksize=2048, coalesce=4, hedge_timeout_s=0.01,
+                            eviction_interval_s=0.01),
         ) as pf:
             assert pf.read_range(0, 16384) == payload(16384)
         assert pf.stats.hedges >= 1
@@ -159,9 +160,9 @@ class TestCoalescedPrefetch:
         store = make_store(objects)
         store.link.fail_next(100)
         with RollingPrefetcher(
-            store, metas(store), [MemTier(32 << 10)], 512,
-            coalesce=4, max_retries=1, retry_backoff_s=0.001,
-            eviction_interval_s=0.01,
+            store, metas(store), [MemTier(32 << 10)],
+            policy=IOPolicy(blocksize=512, coalesce=4, eviction_interval_s=0.01,
+                            retry=RetryPolicy(max_retries=1, backoff_s=0.001)),
         ) as pf:
             with pytest.raises(StoreError):
                 pf.read_range(0, 4096)
@@ -172,8 +173,8 @@ class TestCoalescedPrefetch:
         objects = {"a": payload(8192)}
         store = make_store(objects)
         with RollingPrefetcher(
-            store, metas(store), [MemTier(1024)], 512,  # 2-block budget
-            coalesce=8, eviction_interval_s=0.005,
+            store, metas(store), [MemTier(1024)],
+            policy=IOPolicy(blocksize=512, coalesce=8, eviction_interval_s=0.005),
         ) as pf:
             assert pf.read_range(0, 8192) == payload(8192)
 
@@ -186,8 +187,8 @@ class TestReadaheadHorizon:
         objects = {"a": payload(16384)}
         store = make_store(objects)
         pf = RollingPrefetcher(
-            store, metas(store), [MemTier(64 << 10)], 512,   # 32 blocks
-            readahead_blocks=4, eviction_interval_s=10.0,
+            store, metas(store), [MemTier(64 << 10)],
+            policy=IOPolicy(blocksize=512, readahead_blocks=4, eviction_interval_s=10.0),
         )
         with pf:
             time.sleep(0.25)   # reader never reads: horizon stays [0, 4)
@@ -204,8 +205,9 @@ class TestReadaheadHorizon:
         objects = {"a": payload(16384)}
         store = make_store(objects)
         pf = RollingPrefetcher(
-            store, metas(store), [MemTier(64 << 10)], 512,
-            coalesce=16, readahead_blocks=6, eviction_interval_s=10.0,
+            store, metas(store), [MemTier(64 << 10)],
+            policy=IOPolicy(blocksize=512, coalesce=16, readahead_blocks=6,
+                            eviction_interval_s=10.0),
         )
         with pf:
             time.sleep(0.25)
@@ -215,19 +217,6 @@ class TestReadaheadHorizon:
             )
             assert in_flight <= 6
             assert pf.read_range(0, 16384) == payload(16384)
-
-    def test_validation(self):
-        objects = {"a": payload(1024)}
-        store = make_store(objects)
-        with pytest.raises(ValueError):
-            RollingPrefetcher(store, metas(store), [MemTier(4096)], 256,
-                              readahead_blocks=0)
-        with pytest.raises(ValueError):
-            RollingPrefetcher(store, metas(store), [MemTier(4096)], 256,
-                              coalesce=0)
-        with pytest.raises(ValueError):
-            RollingPrefetcher(store, metas(store), [MemTier(4096)], 256,
-                              depth=4, max_depth=2)
 
 
 # --------------------------------------------------------------------------- #
@@ -269,8 +258,9 @@ class TestAimdDepth:
         objects = {f"f{i}": payload(2048, seed=i) for i in range(8)}
         store = make_store(objects, latency=0.005)
         with RollingPrefetcher(
-            store, metas(store), [MemTier(64 << 10)], 512,
-            depth=1, max_depth=6, eviction_interval_s=0.01,
+            store, metas(store), [MemTier(64 << 10)],
+            policy=IOPolicy(blocksize=512, depth=1, max_depth=6,
+                            eviction_interval_s=0.01),
         ) as pf:
             got = pf.read_range(0, pf.plan.total_bytes)
         assert got == b"".join(objects[m.key] for m in metas(store))
@@ -292,7 +282,8 @@ class TestEvictionNotify:
         tier = MemTier(1024)   # 2 blocks of 512
         t0 = time.perf_counter()
         with RollingPrefetcher(
-            store, metas(store), [tier], 512, eviction_interval_s=30.0,
+            store, metas(store), [tier],
+            policy=IOPolicy(blocksize=512, eviction_interval_s=30.0),
         ) as pf:
             assert pf.read_range(0, 8192) == payload(8192)
         elapsed = time.perf_counter() - t0
@@ -308,8 +299,8 @@ class TestZeroCopyReads:
         objects = {"a": payload(4096)}
         store = make_store(objects)
         with RollingPrefetcher(
-            store, metas(store), [MemTier(16 << 10)], 1024,
-            eviction_interval_s=0.05,
+            store, metas(store), [MemTier(16 << 10)],
+            policy=IOPolicy(blocksize=1024, eviction_interval_s=0.05),
         ) as pf:
             first = pf.read_range(0, 512, view=True)
             assert isinstance(first, memoryview)
@@ -352,7 +343,10 @@ class TestSequentialReadahead:
     def test_multiblock_cache_fills_with_one_request(self):
         objects = {"a": payload(8192)}
         store = make_store(objects)
-        f = SequentialFile(store, metas(store), blocksize=512, cache_blocks=4)
+        f = SequentialFile(
+            store, metas(store),
+            policy=IOPolicy(blocksize=512, cache_blocks=4),
+        )
         assert f.read() == payload(8192)
         assert f.stats.blocks_fetched == 16
         assert f.stats.store_requests == 4      # 4-block runs, one GET each
@@ -361,7 +355,7 @@ class TestSequentialReadahead:
     def test_single_block_cache_keeps_baseline_request_shape(self):
         objects = {"a": payload(4096)}
         store = make_store(objects)
-        f = SequentialFile(store, metas(store), blocksize=512)
+        f = SequentialFile(store, metas(store), policy=IOPolicy(blocksize=512))
         assert f.read() == payload(4096)
         assert f.stats.store_requests == f.stats.blocks_fetched == 8
 
@@ -545,8 +539,8 @@ class TestCoalescedWriteFailureCleanup:
         store = make_store(objects)
         tier = FlakyWriteTier(32 << 10, fail_at=3)
         with RollingPrefetcher(
-            store, metas(store), [tier], 512,
-            coalesce=8, eviction_interval_s=0.01,
+            store, metas(store), [tier],
+            policy=IOPolicy(blocksize=512, coalesce=8, eviction_interval_s=0.01),
         ) as pf:
             with pytest.raises(StoreError):
                 pf.read_range(0, 4096)
@@ -560,8 +554,9 @@ class TestLazyStreamSpawn:
         objects = {"a": payload(32 << 10)}
         store = make_store(objects, latency=0.05)
         pf = RollingPrefetcher(
-            store, metas(store), [MemTier(1 << 20)], 2048,
-            depth=2, max_depth=32, eviction_interval_s=0.01,
+            store, metas(store), [MemTier(1 << 20)],
+            policy=IOPolicy(blocksize=2048, depth=2, max_depth=32,
+                            eviction_interval_s=0.01),
         )
         pf.start()
         assert pf._spawned == 2              # not the 32-thread ceiling
@@ -582,8 +577,8 @@ class TestLazyStreamSpawn:
         store = make_store(objects)
         tier = Enospc(32 << 10)
         with RollingPrefetcher(
-            store, metas(store), [tier], 512,
-            coalesce=4, eviction_interval_s=0.01,
+            store, metas(store), [tier],
+            policy=IOPolicy(blocksize=512, coalesce=4, eviction_interval_s=0.01),
         ) as pf:
             with pytest.raises(StoreError):
                 pf.read_range(0, 2048)

@@ -531,7 +531,7 @@ class TestSharedReaders:
 
         objects = {"f": payload(4 << 10)}
         store = make_store(objects)
-        f = SequentialFile(store, metas(store), blocksize=512)
+        f = SequentialFile(store, metas(store), policy=IOPolicy(blocksize=512))
         assert f.read() == objects["f"]
         assert f.stats.store_requests == f.stats.blocks_fetched == 8
 

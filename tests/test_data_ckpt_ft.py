@@ -14,7 +14,7 @@ from repro.ckpt import (
     restore_checkpoint,
     save_checkpoint,
 )
-from repro.ckpt.manager import CheckpointManager, gc_checkpoints
+from repro.ckpt.manager import RESTORE_POLICY, CheckpointManager, gc_checkpoints
 from repro.data import (
     DataCursor,
     DeviceFeeder,
@@ -28,6 +28,7 @@ from repro.data import (
 )
 from repro.core.rolling import RollingPrefetchFile, RollingPrefetcher
 from repro.ft import RestartManager, run_with_restarts
+from repro.io import IOPolicy, RetryPolicy
 from repro.store import LinkModel, MemTier, SimS3Store
 
 
@@ -36,6 +37,10 @@ def make_store(objects: dict[str, bytes], **kw) -> SimS3Store:
     for k, v in objects.items():
         store.backing.put(k, v)
     return store
+
+
+# `LoaderConfig`'s default reader at 4 KiB blocks.
+POLICY_4K = IOPolicy(blocksize=4096, eviction_interval_s=0.2)
 
 
 # --------------------------------------------------------------------------- #
@@ -70,8 +75,10 @@ class TestTrk:
         store = make_store(objects)
         files = store.backing.list_objects()
         f = RollingPrefetchFile(
-            RollingPrefetcher(store, files, [MemTier(1 << 20)], 4096,
-                              eviction_interval_s=0.01)
+            RollingPrefetcher(
+                store, files, [MemTier(1 << 20)],
+                policy=IOPolicy(blocksize=4096, eviction_interval_s=0.01),
+            )
         )
         with f:
             got = list(iter_streamlines_multi(f, f.size))
@@ -96,8 +103,8 @@ class TestTokenLoader:
         files = store.backing.list_objects()
         out = {}
         for mode in ("rolling", "sequential"):
-            cfg = LoaderConfig(seq_len=128, batch_size=4, mode=mode,
-                               blocksize=4096)
+            cfg = LoaderConfig(seq_len=128, batch_size=4, policy=IOPolicy(
+                engine=mode, blocksize=4096, eviction_interval_s=0.2))
             loader = PrefetchingDataLoader(store, files, [MemTier(1 << 20)], cfg)
             batches = [b for b in loader.batches(max_batches=5)]
             loader.close()
@@ -109,7 +116,7 @@ class TestTokenLoader:
     def test_labels_are_shifted_inputs(self):
         store = self._dataset(n_shards=1)
         files = store.backing.list_objects()
-        cfg = LoaderConfig(seq_len=64, batch_size=2, blocksize=4096)
+        cfg = LoaderConfig(seq_len=64, batch_size=2, policy=POLICY_4K)
         loader = PrefetchingDataLoader(store, files, [MemTier(1 << 20)], cfg)
         inputs, labels = next(iter(loader.batches(max_batches=1)))
         loader.close()
@@ -121,7 +128,7 @@ class TestTokenLoader:
         seen = []
         for host in range(2):
             cfg = LoaderConfig(seq_len=32, batch_size=1, host_id=host,
-                               num_hosts=2, blocksize=4096)
+                               num_hosts=2, policy=POLICY_4K)
             loader = PrefetchingDataLoader(store, files, [MemTier(1 << 20)], cfg)
             assert [m.key for m in loader.my_files] == [
                 m.key for m in files[host::2]
@@ -135,7 +142,7 @@ class TestTokenLoader:
         files = store.backing.list_objects()
 
         def collect(cursor, n):
-            cfg = LoaderConfig(seq_len=64, batch_size=2, blocksize=4096)
+            cfg = LoaderConfig(seq_len=64, batch_size=2, policy=POLICY_4K)
             loader = PrefetchingDataLoader(store, files, [MemTier(1 << 20)],
                                            cfg, cursor=cursor)
             bs = [b for b in loader.batches(max_batches=n)]
@@ -152,7 +159,7 @@ class TestTokenLoader:
     def test_epoch_wraparound(self):
         store = self._dataset(n_shards=1, tokens_per_shard=200)
         files = store.backing.list_objects()
-        cfg = LoaderConfig(seq_len=64, batch_size=2, blocksize=4096)
+        cfg = LoaderConfig(seq_len=64, batch_size=2, policy=POLICY_4K)
         loader = PrefetchingDataLoader(store, files, [MemTier(1 << 20)], cfg)
         batches = [b for b in loader.batches(max_batches=4)]
         loader.close()
@@ -162,7 +169,7 @@ class TestTokenLoader:
     def test_device_feeder(self):
         store = self._dataset(n_shards=1)
         files = store.backing.list_objects()
-        cfg = LoaderConfig(seq_len=32, batch_size=2, blocksize=4096)
+        cfg = LoaderConfig(seq_len=32, batch_size=2, policy=POLICY_4K)
         loader = PrefetchingDataLoader(store, files, [MemTier(1 << 20)], cfg)
         feeder = DeviceFeeder(loader.batches(max_batches=3), depth=2)
         out = list(feeder)
@@ -209,7 +216,8 @@ class TestCheckpoint:
         state = _state()
         save_checkpoint(store, "ckpt", 10, state, extra={"cursor": {"epoch": 1}})
         restored, manifest = restore_checkpoint(
-            store, "ckpt", jax.tree.map(lambda x: x, state), mode=mode
+            store, "ckpt", jax.tree.map(lambda x: x, state),
+            policy=RESTORE_POLICY.replace(engine=mode),
         )
         assert manifest["step"] == 10
         assert manifest["extra"]["cursor"]["epoch"] == 1
@@ -267,7 +275,8 @@ class TestCheckpoint:
         }
         save_checkpoint(store, "ckpt", 3, state)
         restored, manifest = restore_checkpoint(
-            store, "ckpt", state, step=step, blocksize=16)
+            store, "ckpt", state, step=step,
+            policy=RESTORE_POLICY.replace(blocksize=16))
         assert store.size_calls == 0
         assert manifest["step"] == 3
         for name, leaf in state.items():
@@ -281,7 +290,6 @@ class TestCheckpoint:
         """A leaf object cut short in the store after the save fails the
         restore; it never comes back as a short or zero-padded leaf."""
         from repro.ckpt.manager import _leaf_key
-        from repro.io import IOPolicy
         from repro.store.base import StoreError
 
         store = make_store({})
@@ -292,7 +300,7 @@ class TestCheckpoint:
         full = store.backing.get(key)
         store.backing.put(key, full[: len(full) - 100])
         policy = IOPolicy(engine=engine, verify=verify, blocksize=1024,
-                          retry_backoff_s=0.001)
+                          retry=RetryPolicy(backoff_s=0.001))
         with pytest.raises(StoreError):
             restore_checkpoint(store, "ckpt", state, policy=policy)
 
@@ -331,7 +339,8 @@ class TestRestart:
                         "count": jnp.asarray(0, jnp.int32)}
 
             def make_loader(cursor):
-                cfg = LoaderConfig(seq_len=32, batch_size=2, blocksize=2048)
+                cfg = LoaderConfig(seq_len=32, batch_size=2, policy=IOPolicy(
+                    blocksize=2048, eviction_interval_s=0.2))
                 return PrefetchingDataLoader(
                     store, store.backing.list_objects(),
                     [MemTier(1 << 20)], cfg, cursor=cursor,
@@ -368,7 +377,7 @@ class TestRestart:
         store = make_store({})
         save_checkpoint(store, "ckpt", 3, _state())
         store.link.fail_next(2)
-        restored, _ = restore_checkpoint(store, "ckpt", _state(), mode="rolling")
+        restored, _ = restore_checkpoint(store, "ckpt", _state())
         assert int(restored["step"]) == 7
 
 

@@ -276,9 +276,11 @@ class TestEngineHealing:
             FaultSchedule(seed=11).corrupt(
                 ops=("get_range", "get_ranges"), prob=0.1))
         want = b"".join(objects[m.key] for m in metas(store))
-        pf = RollingPrefetcher(store, metas(store), [MemTier(1 << 20)],
-                               blocksize=4096, retry=RETRY,
-                               eviction_interval_s=0.01, verify=verify)
+        pf = RollingPrefetcher(
+            store, metas(store), [MemTier(1 << 20)],
+            policy=IOPolicy(blocksize=4096, retry=RETRY, eviction_interval_s=0.01,
+                            verify=verify),
+        )
         f = RollingPrefetchFile(pf)
         assert f.read() == want            # byte-identical, zero errors
         f.close()
@@ -293,7 +295,10 @@ class TestEngineHealing:
             FaultSchedule(seed=13).corrupt(
                 ops=("get_range", "get_ranges"), prob=0.25))
         want = b"".join(objects[m.key] for m in metas(store))
-        f = SequentialFile(store, metas(store), blocksize=4096, retry=RETRY)
+        f = SequentialFile(
+            store, metas(store),
+            policy=IOPolicy(blocksize=4096, retry=RETRY),
+        )
         assert f.read() == want
         assert f.stats.integrity_failures > 0
         f.close()
@@ -306,8 +311,10 @@ class TestEngineHealing:
             make_store(objects),
             FaultSchedule(seed=3).corrupt(ops=("get_range", "get_ranges"),
                                           times=1))
-        f = SequentialFile(store, metas(store), blocksize=8192,
-                           retry=RETRY, verify="off")
+        f = SequentialFile(
+            store, metas(store),
+            policy=IOPolicy(blocksize=8192, retry=RETRY, verify="off"),
+        )
         assert f.read() != objects["a"]    # corruption sailed through
         assert f.stats.integrity_failures == 0
         f.close()
@@ -320,9 +327,11 @@ class TestEngineHealing:
         store = make_store(objects)
         tier = DirTier(1 << 20, root=str(tmp_path / "t"),
                        faults=FaultSchedule(seed=17).flip_at_rest(prob=0.3))
-        pf = RollingPrefetcher(store, metas(store), [tier], blocksize=4096,
-                               retry=RETRY, eviction_interval_s=10.0,
-                               verify="edges")
+        pf = RollingPrefetcher(
+            store, metas(store), [tier],
+            policy=IOPolicy(blocksize=4096, retry=RETRY, eviction_interval_s=10.0,
+                            verify="edges"),
+        )
         f = RollingPrefetchFile(pf)
         assert f.read() == objects["a"]    # populate the cache
         for _ in range(4):                 # rot fires on later reads
@@ -340,8 +349,11 @@ class TestEngineHealing:
             make_store(objects),
             FaultSchedule(seed=19).corrupt(ops=("get_range", "get_ranges"),
                                            prob=1.0))
-        f = SequentialFile(store, metas(store), blocksize=4096,
-                           retry=RetryPolicy(max_retries=2, backoff_s=0.0))
+        f = SequentialFile(
+            store, metas(store),
+            policy=IOPolicy(blocksize=4096,
+                            retry=RetryPolicy(max_retries=2, backoff_s=0.0)),
+        )
         with pytest.raises(IntegrityError):
             f.read()
         f.close()
@@ -354,10 +366,12 @@ class TestEngineHealing:
             make_store(objects),
             FaultSchedule(seed=19).corrupt(ops=("get_range", "get_ranges"),
                                            prob=1.0))
-        pf = RollingPrefetcher(store, metas(store), [MemTier(1 << 20)],
-                               blocksize=4096,
-                               retry=RetryPolicy(max_retries=2, backoff_s=0.0),
-                               eviction_interval_s=10.0, verify="edges")
+        pf = RollingPrefetcher(
+            store, metas(store), [MemTier(1 << 20)],
+            policy=IOPolicy(blocksize=4096,
+                            retry=RetryPolicy(max_retries=2, backoff_s=0.0),
+                            eviction_interval_s=10.0, verify="edges"),
+        )
         f = RollingPrefetchFile(pf)
         with pytest.raises(IntegrityError):
             f.read()
@@ -475,9 +489,11 @@ class TestAcceptanceChaos:
         want = b"".join(objects[m.key] for m in metas(store))
         tier = DirTier(4 << 20, root=str(tmp_path / "t"),
                        faults=FaultSchedule(seed=31).flip_at_rest(prob=0.05))
-        pf = RollingPrefetcher(store, metas(store), [tier], blocksize=4096,
-                               retry=RETRY, eviction_interval_s=10.0,
-                               verify="edges")
+        pf = RollingPrefetcher(
+            store, metas(store), [tier],
+            policy=IOPolicy(blocksize=4096, retry=RETRY, eviction_interval_s=10.0,
+                            verify="edges"),
+        )
         f = RollingPrefetchFile(pf)
         assert f.read() == want
         f.seek(0)

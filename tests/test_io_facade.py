@@ -1,14 +1,18 @@
-"""PrefetchFS facade tests: registry dispatch, policy overrides, stats
-aggregation, deprecation shims (byte-identical vs. the old constructors),
-and the thread-safety fixes in the rolling engine."""
+"""PrefetchFS facade tests: registry dispatch, the one `IOPolicy` (its
+checks, per-open overrides, the workload class each call site stamps, the
+restore's default), stats aggregation, and the thread-safety fixes in the
+rolling engine."""
 
 from __future__ import annotations
 
 import threading
 import time
 
+import numpy as np
 import pytest
 
+from repro.ckpt import restore_checkpoint, save_checkpoint
+from repro.ckpt.manager import RESTORE_POLICY
 from repro.core.rolling import PrefetchStats, RollingPrefetchFile, RollingPrefetcher
 from repro.core.sequential import SequentialFile
 from repro.data.loader import LoaderConfig, PrefetchingDataLoader
@@ -17,10 +21,12 @@ from repro.io import (
     IOPolicy,
     PrefetchFS,
     Reader,
+    RetryPolicy,
     available_engines,
     register_reader,
 )
 from repro.io import registry as io_registry
+from repro.serve.engine import ServeEngine
 from repro.store import LinkModel, MemTier, SimS3Store
 from repro.store.base import ObjectMeta, ObjectStore, StoreError, TransientStoreError
 
@@ -38,6 +44,26 @@ def make_store(objects: dict[str, bytes], **kw) -> SimS3Store:
 
 def metas(store) -> list[ObjectMeta]:
     return store.backing.list_objects()
+
+
+def _checkpoint(store) -> dict:
+    """Save a one-leaf checkpoint at step 1 under "ckpt"; returns its state."""
+    state = {"w": np.arange(64, dtype=np.float32).reshape(8, 8)}
+    save_checkpoint(store, "ckpt", 1, state)
+    return state
+
+
+def _fs_policies(monkeypatch, module: str) -> list[IOPolicy]:
+    """Record the policy of every `PrefetchFS` that `module` opens."""
+    seen: list[IOPolicy] = []
+
+    class Recording(PrefetchFS):
+        def __init__(self, store, policy=None, **kw):
+            seen.append(policy)
+            super().__init__(store, policy=policy, **kw)
+
+    monkeypatch.setattr(f"{module}.PrefetchFS", Recording)
+    return seen
 
 
 OBJECTS = {f"f{i}": payload(1500 + 37 * i, seed=i) for i in range(3)}
@@ -98,29 +124,16 @@ class TestRegistry:
 # IOPolicy
 # --------------------------------------------------------------------------- #
 class TestIOPolicy:
-    def test_validation(self):
+    @pytest.mark.parametrize("bad", [
+        dict(blocksize=0), dict(depth=0), dict(depth=4, max_depth=2),
+        dict(coalesce=0), dict(readahead_blocks=0), dict(write_depth=0),
+        dict(max_hedges=0), dict(verify="paranoid"), dict(io_class=""),
+    ], ids=lambda kw: ",".join(f"{k}={v!r}" for k, v in kw.items()))
+    def test_validation(self, bad):
+        """Every reader field is checked once, here; the engines take the
+        policy as it is."""
         with pytest.raises(ValueError):
-            IOPolicy(blocksize=0)
-        with pytest.raises(ValueError):
-            IOPolicy(depth=0)
-
-    def test_from_config_mapping_ignores_unknown_keys(self):
-        p = IOPolicy.from_config(
-            {"engine": "sequential", "blocksize": 123, "bogus_key": 1},
-            depth=3,
-        )
-        assert p.engine == "sequential"
-        assert p.blocksize == 123
-        assert p.depth == 3
-
-    def test_from_config_object_attributes(self):
-        class Cfg:
-            engine = "direct"
-            blocksize = 777
-            unrelated = "x"
-
-        p = IOPolicy.from_config(Cfg())
-        assert (p.engine, p.blocksize) == ("direct", 777)
+            IOPolicy(**bad)
 
     def test_per_open_override_does_not_mutate_fs_policy(self):
         store = make_store(OBJECTS)
@@ -132,6 +145,41 @@ class TestIOPolicy:
             assert f.plan.blocksize == 64
             assert fs.policy.engine == "rolling"
             assert fs.policy.blocksize == 512
+
+    @pytest.mark.parametrize("site,stamped", [
+        ("loader", "loader"), ("restore", "ckpt"), ("from_store", "serve"),
+    ])
+    @pytest.mark.parametrize("explicit", [False, True], ids=["default", "explicit"])
+    def test_call_site_stamps_its_workload_class(self, monkeypatch, site, stamped,
+                                                  explicit):
+        """A policy left at io_class="default" reaches the store as the call
+        site's class; a caller's own class is kept."""
+        policy = IOPolicy(eviction_interval_s=0.01,
+                          io_class="eval" if explicit else "default")
+        store = make_store(OBJECTS)
+        if site == "loader":
+            seen = _fs_policies(monkeypatch, "repro.data.loader")
+            cfg = LoaderConfig(seq_len=8, batch_size=2, policy=policy)
+            PrefetchingDataLoader(store, metas(store), [MemTier(1 << 20)], cfg).close()
+        else:
+            state = _checkpoint(store)
+            seen = _fs_policies(monkeypatch, "repro.ckpt.manager")
+            if site == "restore":
+                restore_checkpoint(store, "ckpt", state, policy=policy)
+            else:
+                ServeEngine.from_store(None, store, "ckpt", state, policy=policy)
+        assert [p.io_class for p in seen] == ["eval" if explicit else stamped]
+
+    def test_from_store_restores_with_the_restore_default(self, monkeypatch):
+        """A cold start that names no policy reads exactly the restore's own
+        default, stamped as serving traffic: one default, written once."""
+        store = make_store({})
+        state = _checkpoint(store)
+        seen = _fs_policies(monkeypatch, "repro.ckpt.manager")
+        ServeEngine.from_store(None, store, "ckpt", state)
+        assert seen == [IOPolicy(engine="rolling", blocksize=8 << 20, depth=2,
+                                 eviction_interval_s=0.2, io_class="serve")]
+        assert seen == [RESTORE_POLICY.replace(io_class="serve")]
 
 
 # --------------------------------------------------------------------------- #
@@ -224,81 +272,6 @@ class TestFacadeReads:
 
 
 # --------------------------------------------------------------------------- #
-# deprecation shims: warn AND stay byte-identical
-# --------------------------------------------------------------------------- #
-class TestDeprecationShims:
-    def test_rolling_open_classmethod(self):
-        store = make_store(OBJECTS)
-        with pytest.warns(DeprecationWarning, match="RollingPrefetchFile.open"):
-            f = RollingPrefetchFile.open(
-                store, metas(store), [MemTier(8192)], 256,
-                eviction_interval_s=0.01,
-            )
-        assert isinstance(f, RollingPrefetchFile)
-        with f:
-            old = f.read()
-
-        store = make_store(OBJECTS)
-        fs = PrefetchFS(store, policy=IOPolicy(engine="rolling", blocksize=256,
-                                               eviction_interval_s=0.01),
-                        tiers=[MemTier(8192)])
-        with fs:
-            new = fs.open_many(metas(store)).read()
-        assert old == new == WANT
-
-    def test_loader_mode_kwarg(self):
-        store = make_store(OBJECTS)
-        cfg = LoaderConfig(seq_len=8, batch_size=2, mode="sequential",
-                           blocksize=256)
-        with pytest.warns(DeprecationWarning, match="LoaderConfig"):
-            loader = PrefetchingDataLoader(store, metas(store),
-                                           [MemTier(1 << 20)], cfg)
-        loader.close()
-
-    def test_loader_mode_and_policy_paths_identical(self):
-        import numpy as np
-
-        from repro.data import synth_token_shard
-
-        rng = np.random.default_rng(3)
-        objects = {f"tok{i}.bin": synth_token_shard(rng, 4000)
-                   for i in range(2)}
-        out = {}
-        for name, kw in [
-            ("legacy", dict(mode="rolling", blocksize=4096)),
-            ("policy", dict(policy=IOPolicy(engine="rolling", blocksize=4096,
-                                            eviction_interval_s=0.2))),
-        ]:
-            store = make_store(objects)
-            cfg = LoaderConfig(seq_len=64, batch_size=2, **kw)
-            loader = PrefetchingDataLoader(store, metas(store),
-                                           [MemTier(1 << 20)], cfg)
-            out[name] = [b for b in loader.batches(max_batches=3)]
-            loader.close()
-        for (i1, l1), (i2, l2) in zip(out["legacy"], out["policy"]):
-            np.testing.assert_array_equal(i1, i2)
-            np.testing.assert_array_equal(l1, l2)
-
-    def test_restore_mode_kwarg(self):
-        import jax
-        import numpy as np
-
-        from repro.ckpt import restore_checkpoint, save_checkpoint
-
-        state = {"w": np.arange(64, dtype=np.float32).reshape(8, 8),
-                 "step": np.int32(7)}
-        store = make_store({})
-        save_checkpoint(store, "ckpt", 1, state)
-        with pytest.warns(DeprecationWarning, match="restore_checkpoint"):
-            legacy, _ = restore_checkpoint(store, "ckpt", state,
-                                           mode="sequential")
-        modern, _ = restore_checkpoint(
-            store, "ckpt", state, policy=IOPolicy(engine="sequential"))
-        for a, b in zip(jax.tree.leaves(legacy), jax.tree.leaves(modern)):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-# --------------------------------------------------------------------------- #
 # rolling-engine thread-safety fixes
 # --------------------------------------------------------------------------- #
 class _SlowFailThenSlowSuccessStore(ObjectStore):
@@ -343,8 +316,8 @@ class TestRollingThreadSafety:
         store = _SlowFailThenSlowSuccessStore(data)
         pf = RollingPrefetcher(
             store, [ObjectMeta("a", len(data))], [MemTier(4096)],
-            blocksize=512, hedge_timeout_s=0.005, max_retries=0,
-            eviction_interval_s=0.01,
+            policy=IOPolicy(blocksize=512, hedge_timeout_s=0.005,
+                            retry=RetryPolicy(max_retries=0), eviction_interval_s=0.01),
         )
         with pf:
             assert pf.read_range(0, len(data)) == data
@@ -356,7 +329,7 @@ class TestRollingThreadSafety:
         store.link.fail_next(100)
         fs = PrefetchFS(store, policy=IOPolicy(
             engine="rolling", blocksize=256, hedge_timeout_s=0.005,
-            max_retries=1, retry_backoff_s=0.001, eviction_interval_s=0.01,
+            retry=RetryPolicy(max_retries=1, backoff_s=0.001), eviction_interval_s=0.01,
         ))
         with fs:
             f = fs.open_many(metas(store))

@@ -31,10 +31,10 @@ def store():
     return s
 
 
-def _loader(store, host, cursor=None, **kw):
+def _loader(store, host, cursor=None, **policy):
     cfg = LoaderConfig(
-        seq_len=64, batch_size=2, blocksize=4096,
-        host_id=host, num_hosts=N_HOSTS, **kw,
+        seq_len=64, batch_size=2, host_id=host, num_hosts=N_HOSTS,
+        policy=IOPolicy(blocksize=4096, eviction_interval_s=0.2, **policy),
     )
     return PrefetchingDataLoader(
         store, store.backing.list_objects(), [MemTier(1 << 20)], cfg,
@@ -106,7 +106,7 @@ class TestMultiHost:
     def test_transient_store_failures_do_not_corrupt_streams(self, store):
         store.link.fail_prob = 0.02
         store.link._rng.seed(123)
-        loader = _loader(store, 0, mode="rolling")
+        loader = _loader(store, 0)
         batches = [b for b in loader.batches(max_batches=4)]
         loader.close()
         store.link.fail_prob = 0.0

@@ -492,9 +492,9 @@ class TestThrottleAimd:
                                          every=4)
         pf = RollingPrefetcher(
             FaultyStore(store, sched), metas(store), [MemTier(1 << 20)],
-            blocksize=2048, depth=8, max_depth=8,
-            retry=RetryPolicy(max_retries=8, backoff_s=0.001),
-            eviction_interval_s=0.01,
+            policy=IOPolicy(blocksize=2048, depth=8, max_depth=8,
+                            retry=RetryPolicy(max_retries=8, backoff_s=0.001),
+                            eviction_interval_s=0.01),
         )
         f = RollingPrefetchFile(pf)
         assert f.read() == objects["a"]
@@ -511,9 +511,9 @@ class TestThrottleAimd:
         throttle_cuts = []
         pf = RollingPrefetcher(
             FaultyStore(store, sched), metas(store), [MemTier(1 << 20)],
-            blocksize=2048, depth=4, max_depth=4, throttle_aimd=False,
-            retry=RetryPolicy(max_retries=8, backoff_s=0.001),
-            eviction_interval_s=0.01,
+            policy=IOPolicy(blocksize=2048, depth=4, max_depth=4, throttle_aimd=False,
+                            retry=RetryPolicy(max_retries=8, backoff_s=0.001),
+                            eviction_interval_s=0.01),
         )
         pf._aimd.on_throttle = lambda: throttle_cuts.append(1)  # spy
         f = RollingPrefetchFile(pf)
@@ -570,8 +570,10 @@ class TestChaosEndToEnd:
         objects = self._dataset()
         store = FaultyStore(make_store(objects), chaos_schedule(seed=13))
         want = b"".join(objects[m.key] for m in metas(store))
-        f = SequentialFile(store, metas(store), blocksize=4096,
-                           retry=self.RETRY)
+        f = SequentialFile(
+            store, metas(store),
+            policy=IOPolicy(blocksize=4096, retry=self.RETRY),
+        )
         assert f.read() == want
         assert f.stats.retries > 0
         f.close()
@@ -580,7 +582,7 @@ class TestChaosEndToEnd:
         objects = {"a": payload(4096)}
         store = make_store(objects)
         store.link.fail_next(1)
-        f = SequentialFile(store, metas(store), blocksize=1024)
+        f = SequentialFile(store, metas(store), policy=IOPolicy(blocksize=1024))
         # Old behaviour: TransientStoreError propagated to the caller.
         assert f.read() == objects["a"]
         assert f.stats.retries == 1
@@ -673,9 +675,9 @@ class TestChaosEndToEnd:
             FaultSchedule(seed=37).stall(0.03, ops=("get_range",
                                                     "get_ranges"), prob=1.0))
         pf = RollingPrefetcher(
-            store, metas(store), [MemTier(1 << 20)], blocksize=4096,
-            depth=4, hedge_timeout_s=0.002, max_hedges=2,
-            retry=self.RETRY, eviction_interval_s=0.01,
+            store, metas(store), [MemTier(1 << 20)],
+            policy=IOPolicy(blocksize=4096, depth=4, hedge_timeout_s=0.002,
+                            max_hedges=2, retry=self.RETRY, eviction_interval_s=0.01),
         )
         f = RollingPrefetchFile(pf)
         assert f.read() == objects["a"]
@@ -713,9 +715,10 @@ class TestChaosEndToEnd:
         store = FaultyStore(
             make_store(objects),
             FaultSchedule().truncate(nbytes=3, ops=("get_range",), times=1))
-        pf = RollingPrefetcher(store, metas(store), [MemTier(1 << 20)],
-                               blocksize=2048, retry=self.RETRY,
-                               eviction_interval_s=0.01)
+        pf = RollingPrefetcher(
+            store, metas(store), [MemTier(1 << 20)],
+            policy=IOPolicy(blocksize=2048, retry=self.RETRY, eviction_interval_s=0.01),
+        )
         f = RollingPrefetchFile(pf)
         assert f.read() == objects["a"]   # NOT silently short
         f.close()

@@ -8,13 +8,8 @@ import time
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core import (
-    BlockPlan,
-    BlockState,
-    RollingPrefetcher,
-    RollingPrefetchFile,
-    SequentialFile,
-)
+from repro.core import BlockPlan, BlockState, RollingPrefetcher, SequentialFile
+from repro.io import IOPolicy, PrefetchFS, RetryPolicy
 from repro.store import LinkModel, MemTier, SimS3Store
 from repro.store.base import ObjectMeta, StoreError
 
@@ -33,6 +28,12 @@ def payload(n: int, seed: int = 0) -> bytes:
 
 def metas(store) -> list[ObjectMeta]:
     return store.backing.list_objects()
+
+
+def open_rolling(store, tiers, **policy):
+    """A rolling reader over every object in `store`, as one stream."""
+    fs = PrefetchFS(store, policy=IOPolicy(engine="rolling", **policy), tiers=tiers)
+    return fs.open_many(metas(store))
 
 
 # --------------------------------------------------------------------------- #
@@ -84,8 +85,8 @@ class TestRollingPrefetch:
         objects = {f"f{i}": payload(1000 + i * 37, seed=i) for i in range(4)}
         store = make_store(objects)
         tiers = [MemTier(capacity=4096)]
-        with RollingPrefetchFile.open(
-            store, metas(store), tiers, blocksize=256, eviction_interval_s=0.01
+        with open_rolling(
+            store, tiers, blocksize=256, eviction_interval_s=0.01
         ) as f:
             got = f.read()
         want = b"".join(objects[m.key] for m in metas(store))
@@ -94,8 +95,8 @@ class TestRollingPrefetch:
     def test_chunked_reads_match_full_read(self):
         objects = {"a": payload(5000), "b": payload(3000, seed=1)}
         store = make_store(objects)
-        with RollingPrefetchFile.open(
-            store, metas(store), [MemTier(8192)], blocksize=512,
+        with open_rolling(
+            store, [MemTier(8192)], blocksize=512,
             eviction_interval_s=0.01,
         ) as f:
             chunks = []
@@ -122,8 +123,8 @@ class TestRollingPrefetch:
 
         t = threading.Thread(target=monitor, daemon=True)
         t.start()
-        with RollingPrefetchFile.open(
-            store, metas(store), [tier], blocksize=256, eviction_interval_s=0.001
+        with open_rolling(
+            store, [tier], blocksize=256, eviction_interval_s=0.001
         ) as f:
             data = f.read()
         stop.set()
@@ -136,8 +137,8 @@ class TestRollingPrefetch:
         objects = {f"f{i}": payload(4096, seed=i) for i in range(4)}
         store = make_store(objects)
         tier = MemTier(capacity=512)  # far smaller than 16 KiB dataset
-        with RollingPrefetchFile.open(
-            store, metas(store), [tier], blocksize=256, eviction_interval_s=0.001
+        with open_rolling(
+            store, [tier], blocksize=256, eviction_interval_s=0.001
         ) as f:
             want = b"".join(objects[m.key] for m in metas(store))
             assert f.read() == want
@@ -148,8 +149,8 @@ class TestRollingPrefetch:
         store = make_store(objects)
         t0, t1 = MemTier(capacity=256, name="t0"), MemTier(capacity=4096, name="t1")
         pf = RollingPrefetcher(
-            store, metas(store), [t0, t1], blocksize=256,
-            eviction_interval_s=10.0,  # effectively no eviction during test
+            store, metas(store), [t0, t1],
+            policy=IOPolicy(blocksize=256, eviction_interval_s=10.0),
         )
         with pf:
             # Wait until prefetching stalls or finishes.
@@ -169,7 +170,8 @@ class TestRollingPrefetch:
         store = make_store(objects)
         tier = MemTier(capacity=2048)
         pf = RollingPrefetcher(
-            store, metas(store), [tier], blocksize=256, eviction_interval_s=0.001
+            store, metas(store), [tier],
+            policy=IOPolicy(blocksize=256, eviction_interval_s=0.001),
         )
         with pf:
             pf.read_range(0, 1024)
@@ -181,8 +183,8 @@ class TestRollingPrefetch:
     def test_seek_forward_and_tell(self):
         objects = {"a": payload(1000), "b": payload(1000, seed=2)}
         store = make_store(objects)
-        with RollingPrefetchFile.open(
-            store, metas(store), [MemTier(4096)], blocksize=128,
+        with open_rolling(
+            store, [MemTier(4096)], blocksize=128,
             eviction_interval_s=0.01,
         ) as f:
             f.seek(500)
@@ -194,8 +196,8 @@ class TestRollingPrefetch:
     def test_backward_seek_after_eviction_falls_back_to_direct_read(self):
         objects = {"a": payload(1024)}
         store = make_store(objects)
-        with RollingPrefetchFile.open(
-            store, metas(store), [MemTier(4096)], blocksize=128,
+        with open_rolling(
+            store, [MemTier(4096)], blocksize=128,
             eviction_interval_s=0.001,
         ) as f:
             first = f.read(512)
@@ -209,9 +211,9 @@ class TestRollingPrefetch:
         objects = {"a": payload(2048)}
         store = make_store(objects)
         store.link.fail_next(2)
-        with RollingPrefetchFile.open(
-            store, metas(store), [MemTier(4096)], blocksize=512,
-            eviction_interval_s=0.01, max_retries=5, retry_backoff_s=0.001,
+        with open_rolling(
+            store, [MemTier(4096)], blocksize=512, eviction_interval_s=0.01,
+            retry=RetryPolicy(max_retries=5, backoff_s=0.001),
         ) as f:
             assert f.read() == payload(2048)
         assert f.stats.retries >= 2
@@ -220,9 +222,9 @@ class TestRollingPrefetch:
         objects = {"a": payload(2048)}
         store = make_store(objects)
         store.link.fail_next(100)
-        with RollingPrefetchFile.open(
-            store, metas(store), [MemTier(4096)], blocksize=512,
-            eviction_interval_s=0.01, max_retries=1, retry_backoff_s=0.001,
+        with open_rolling(
+            store, [MemTier(4096)], blocksize=512, eviction_interval_s=0.01,
+            retry=RetryPolicy(max_retries=1, backoff_s=0.001),
         ) as f:
             with pytest.raises(StoreError):
                 f.read()
@@ -230,8 +232,8 @@ class TestRollingPrefetch:
     def test_depth_gt_one_still_correct(self):
         objects = {f"f{i}": payload(3000, seed=i) for i in range(3)}
         store = make_store(objects, latency=0.002)
-        with RollingPrefetchFile.open(
-            store, metas(store), [MemTier(16384)], blocksize=512,
+        with open_rolling(
+            store, [MemTier(16384)], blocksize=512,
             depth=4, eviction_interval_s=0.01,
         ) as f:
             want = b"".join(objects[m.key] for m in metas(store))
@@ -240,8 +242,8 @@ class TestRollingPrefetch:
     def test_hedged_fetch_fires_on_straggler(self):
         objects = {"a": payload(4096)}
         store = make_store(objects, latency=0.05)
-        with RollingPrefetchFile.open(
-            store, metas(store), [MemTier(8192)], blocksize=1024,
+        with open_rolling(
+            store, [MemTier(8192)], blocksize=1024,
             hedge_timeout_s=0.01, eviction_interval_s=0.01,
         ) as f:
             assert f.read() == payload(4096)
@@ -259,8 +261,8 @@ class TestRollingPrefetch:
         the concatenated object bytes."""
         objects = {f"f{i}": payload(size, seed=i) for i in range(nfiles)}
         store = make_store(objects)
-        with RollingPrefetchFile.open(
-            store, metas(store), [MemTier(max(blocksize * 4, 2048))],
+        with open_rolling(
+            store, [MemTier(max(blocksize * 4, 2048))],
             blocksize=blocksize, eviction_interval_s=0.001,
         ) as f:
             got = bytearray()
@@ -279,8 +281,8 @@ class TestRollingPrefetch:
         objects = {"a": payload(1024)}
         store = make_store(objects)
         pf = RollingPrefetcher(
-            store, metas(store), [MemTier(4096)], blocksize=256,
-            eviction_interval_s=10.0,
+            store, metas(store), [MemTier(4096)],
+            policy=IOPolicy(blocksize=256, eviction_interval_s=10.0),
         )
 
         def broken_reserve(nbytes):
@@ -303,10 +305,10 @@ class TestSequentialFile:
     def test_matches_rolling_output(self):
         objects = {f"f{i}": payload(1500, seed=i) for i in range(3)}
         store = make_store(objects)
-        seq = SequentialFile(store, metas(store), blocksize=400)
+        seq = SequentialFile(store, metas(store), policy=IOPolicy(blocksize=400))
         data_seq = seq.read()
-        with RollingPrefetchFile.open(
-            store, metas(store), [MemTier(8192)], blocksize=400,
+        with open_rolling(
+            store, [MemTier(8192)], blocksize=400,
             eviction_interval_s=0.01,
         ) as f:
             data_pf = f.read()
@@ -317,7 +319,7 @@ class TestSequentialFile:
         latency per block fetched."""
         objects = {"a": payload(4096)}
         store = make_store(objects, latency=0.01)
-        seq = SequentialFile(store, metas(store), blocksize=1024)
+        seq = SequentialFile(store, metas(store), policy=IOPolicy(blocksize=1024))
         t0 = time.perf_counter()
         seq.read()
         elapsed = time.perf_counter() - t0
@@ -339,7 +341,10 @@ class TestOverlap:
 
         def run_sequential():
             store = make_store(objects, latency=per_block_cloud)
-            f = SequentialFile(store, metas(store), blocksize=blocksize)
+            f = SequentialFile(
+                store, metas(store),
+                policy=IOPolicy(blocksize=blocksize),
+            )
             t0 = time.perf_counter()
             while True:
                 chunk = f.read(blocksize)
@@ -350,8 +355,8 @@ class TestOverlap:
 
         def run_rolling():
             store = make_store(objects, latency=per_block_cloud)
-            with RollingPrefetchFile.open(
-                store, metas(store), [MemTier(nbytes)], blocksize=blocksize,
+            with open_rolling(
+                store, [MemTier(nbytes)], blocksize=blocksize,
                 eviction_interval_s=0.005,
             ) as f:
                 t0 = time.perf_counter()

@@ -14,7 +14,7 @@ import pytest
 from jax._src import monitoring
 
 from repro.ckpt import restore_checkpoint, save_checkpoint
-from repro.ckpt.manager import RESTORE_HOST_SPANS, restore_breakdown
+from repro.ckpt.manager import RESTORE_HOST_SPANS, RESTORE_POLICY, restore_breakdown
 from repro.data import DeviceFeeder
 from repro.store import LinkModel, SimS3Store
 from repro.utils import trace
@@ -139,7 +139,8 @@ def test_restore_spans_and_request_counters(step):
     save_checkpoint(store, "ckpt", 4, state)
     before = store.link.requests
     with trace.recording() as rec:
-        restored, _ = restore_checkpoint(store, "ckpt", state, step=step, blocksize=1024)
+        restored, _ = restore_checkpoint(store, "ckpt", state, step=step,
+                                         policy=RESTORE_POLICY.replace(blocksize=1024))
         jax.block_until_ready(restored)
     made = store.link.requests - before
     leaves = len(jax.tree.leaves(state))

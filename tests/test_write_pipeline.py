@@ -16,6 +16,7 @@ from repro.core.rolling import RollingPrefetcher
 from repro.io import (
     IOPolicy,
     PrefetchFS,
+    RetryPolicy,
     Writer,
     available_stores,
     clear_store_cache,
@@ -147,7 +148,7 @@ class TestStoreRegistry:
 def make_fs(uri="sims3://wtest?latency_ms=1", **policy_kw) -> PrefetchFS:
     policy_kw.setdefault("blocksize", 1024)
     policy_kw.setdefault("write_depth", 3)
-    policy_kw.setdefault("retry_backoff_s", 0.001)
+    policy_kw.setdefault("retry", RetryPolicy(backoff_s=0.001))
     policy_kw.setdefault("eviction_interval_s", 0.01)
     return PrefetchFS(open_store(uri, fresh=True),
                       policy=IOPolicy(**policy_kw))
@@ -207,7 +208,7 @@ class TestWriter:
         assert w.stats.snapshot()["retries"] >= 2
 
     def test_permanent_failure_raises_and_never_publishes(self):
-        fs = make_fs(max_retries=1)
+        fs = make_fs(retry=RetryPolicy(max_retries=1, backoff_s=0.001))
         fs.store.put_link.fail_next(1000)
         w = fs.open_write("doomed")
         w.write(payload(5000))
@@ -423,7 +424,8 @@ class TestCheckpointWritePath:
         store.put_link.fail_next(1000)
         with pytest.raises(StoreError):
             save_checkpoint(store, "ckpt", 5, self._state(),
-                            policy=IOPolicy(max_retries=0, blocksize=1024))
+                            policy=IOPolicy(retry=RetryPolicy(max_retries=0),
+                                            blocksize=1024))
         # inspect the substrate directly: the failed step must be invisible
         assert latest_step(store.backing, "ckpt") is None
 
@@ -444,8 +446,10 @@ class TestSatellites:
     def test_rolling_prefetcher_refuses_restart_after_close(self):
         store = open_store("mem://rp", fresh=True)
         store.put("a", payload(256))
-        pf = RollingPrefetcher(store, [ObjectMeta("a", 256)], [MemTier(4096)],
-                               blocksize=64, eviction_interval_s=0.01)
+        pf = RollingPrefetcher(
+            store, [ObjectMeta("a", 256)], [MemTier(4096)],
+            policy=IOPolicy(blocksize=64, eviction_interval_s=0.01),
+        )
         with pf:
             assert pf.read_range(0, 256) == payload(256)
         pf.close()   # double close is a no-op
