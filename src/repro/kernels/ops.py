@@ -30,7 +30,7 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_scan(x, dt_a, b_proj, c_proj, *, chunk: int = 256,
              initial_state=None, interpret: bool = False):
-    """Fused Mamba-2 SSD scan. x: (B,S,H,P) dt-scaled; returns (y, state)."""
+    """Fused Mamba-2 SSD scan. x: (B,S,H,P) dt-scaled; returns (y, state); has a VJP."""
     return _ssd.ssd_scan(
         x, dt_a, b_proj, c_proj, chunk=chunk,
         initial_state=initial_state, interpret=interpret,

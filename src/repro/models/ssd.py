@@ -3,10 +3,12 @@
 The chunked SSD algorithm decomposes the selective-state-space recurrence
 into dense per-chunk matmuls (MXU-friendly) plus a short `lax.scan` over
 chunk states — this is the published TPU/accelerator-native formulation of
-the Mamba recurrence [arXiv:2405.21060]. The intra-chunk computation is
-also implemented as a Pallas kernel (repro.kernels.ssd_scan); this module
-is the pure-jnp path used by smoke tests and the dry-run, and doubles as
-the kernel's oracle.
+the Mamba recurrence [arXiv:2405.21060]. `ssd_chunked` is that algorithm
+in jnp: the scan of every lowering that is not for a TPU (the CPU tests,
+the dry-run), of a decode-free call that the fused kernel does not tile
+or that runs under a mesh, and the oracle of the fused Pallas kernel
+(repro.kernels.ssd_scan, forward and backward). `mamba_block` hands a
+call that fits to the kernel where the lowering is for a TPU.
 
 Sharding: d_inner (and therefore SSM heads, which tile d_inner in
 head_dim-sized groups) shards over the tensor axis; B/C group projections
@@ -22,8 +24,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels import ops as kernel_ops
+from repro.kernels.ssd_scan import CHUNK_MULTIPLE, HEAD_BLOCK, HEAD_DIMS, STATE_DIMS
 from repro.models.spec import ParamSpec
-from repro.sharding.rules import constrain
+from repro.sharding.rules import constrain, current_rules
+from repro.utils import trace
 
 
 # --------------------------------------------------------------------------- #
@@ -106,6 +111,52 @@ def ssd_chunked(
 
     y = (y_diag + y_off).reshape(bsz, s, h, p)
     return y.astype(x.dtype), final_state
+
+
+def _ssd_kernel_fits(x: jax.Array, b_proj: jax.Array, chunk: int) -> bool:
+    """True for what the fused kernel computes: a scan over more than one
+    position (no decode step), in chunks of a size it tiles, of a head size
+    and state size it takes, with each B/C group's heads in whole head
+    blocks, and no mesh of more than one device to shard it."""
+    rules = current_rules()
+    _, s, h, p = x.shape
+    g, n = b_proj.shape[2], b_proj.shape[3]
+    return (s > 1 and chunk % CHUNK_MULTIPLE == 0
+            and p in HEAD_DIMS and n in STATE_DIMS and (h // g) % HEAD_BLOCK == 0
+            and (rules is None or rules.mesh is None or rules.mesh.size == 1))
+
+
+def _ssd_core(
+    x: jax.Array,        # (B, S, H, P) — dt-scaled inputs
+    dt_a: jax.Array,     # (B, S, H)
+    b_proj: jax.Array,   # (B, S, G, N)
+    c_proj: jax.Array,   # (B, S, G, N)
+    chunk: int,
+    initial_state: jax.Array | None = None,  # (B, H, P, N) fp32
+) -> tuple[jax.Array, jax.Array]:
+    """`ssd_chunked` over S padded to whole chunks, by the fused kernel
+    where it fits and the lowering is for a TPU. Counts `ssd.kernel` or
+    `ssd.xla` in `repro.utils.trace` for the path each lowered call holds."""
+    s = x.shape[1]
+    fits = _ssd_kernel_fits(x, b_proj, chunk)
+    pad = (-s) % chunk
+    if pad:
+        x, dt_a, b_proj, c_proj = (
+            jnp.pad(u, ((0, 0), (0, pad)) + ((0, 0),) * (u.ndim - 2))
+            for u in (x, dt_a, b_proj, c_proj))
+
+    def xla(x, dt_a, b_proj, c_proj):
+        y, state = ssd_chunked(x, dt_a, b_proj, c_proj, chunk, initial_state)
+        return trace.tag(y[:, :s], "ssd.xla"), state
+
+    def kernel(x, dt_a, b_proj, c_proj):
+        y, state = kernel_ops.ssd_scan(x, dt_a, b_proj, c_proj, chunk=chunk,
+                                       initial_state=initial_state)
+        return trace.tag(y[:, :s], "ssd.kernel"), state
+
+    if not fits:
+        return xla(x, dt_a, b_proj, c_proj)
+    return jax.lax.platform_dependent(x, dt_a, b_proj, c_proj, default=xla, tpu=kernel)
 
 
 def ssd_decode_step(
@@ -266,19 +317,12 @@ def mamba_block(
             bg[:, 0],
             cg[:, 0],
         )
-        y = y_t[:, None]
+        y = trace.tag(y_t[:, None], "ssd.xla")
     else:
         init = cache.ssm if cache is not None else None
-        pad = (-s) % cfg.ssm_chunk
-        if pad:
-            x_scaled = jnp.pad(x_scaled, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            dt_a = jnp.pad(dt_a, ((0, 0), (0, pad), (0, 0)))
-            bg = jnp.pad(bg, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            cg = jnp.pad(cg, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        y_full, new_ssm = ssd_chunked(
+        y, new_ssm = _ssd_core(
             x_scaled.astype(dt), dt_a, bg, cg, cfg.ssm_chunk, initial_state=init
         )
-        y = y_full[:, :s]
 
     y = y + xh * p["d_skip"].astype(dt)[None, None, :, None]
     y = y.reshape(bsz, s, cfg.d_inner)

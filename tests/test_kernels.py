@@ -14,6 +14,7 @@ from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ref import attention_ref, ssd_ref
 from repro.kernels.ssd_scan import ssd_scan
 from repro.models import layers as L
+from repro.models import ssd as S
 from repro.models.ssd import ssd_chunked
 from repro.sharding.rules import TRAIN_RULES, ShardingRules, use_rules
 from repro.utils import trace
@@ -246,6 +247,7 @@ SSD_CASES = [
     (1, 512, 4, 4, 64, 128, 128, jnp.float32),
     (1, 256, 4, 1, 64, 128, 256, jnp.float32),   # single chunk
     (2, 256, 4, 1, 32, 64, 64, jnp.bfloat16),
+    (1, 256, 16, 2, 64, 128, 128, jnp.bfloat16),   # G > 1, whole head blocks
 ]
 
 
@@ -287,6 +289,49 @@ def test_ssd_chunked_jnp_matches_sequential_ref(case):
                                rtol=2e-3, atol=2e-3)
 
 
+def _ssd_value_and_grads(scan, case, with_state: bool):
+    """(y, final state) of `scan` and the cotangents of x, dt_a, B, C and,
+    `with_state`, of the initial state, against fixed output weights."""
+    b, s, h, _, p, n = case[:6]
+    ks = jax.random.split(jax.random.key(7), 3)
+    args = list(_ssd_inputs(case))
+    if with_state:
+        args.append(jax.random.normal(ks[0], (b, h, p, n)) * 0.3)
+    y, vjp = jax.vjp(lambda *a: scan(*a[:4], a[4] if with_state else None), *args)
+    w_y = jax.random.normal(ks[1], (b, s, h, p)).astype(y[0].dtype)
+    w_state = jax.random.normal(ks[2], (b, h, p, n))
+    return [*y, *vjp((w_y, w_state))]
+
+
+@pytest.mark.parametrize("with_state", [False, True], ids=["zero_state", "initial_state"])
+@pytest.mark.parametrize("case", SSD_CASES, ids=[str(c[:7]) for c in SSD_CASES])
+def test_ssd_kernel_grads_match_chunked_and_ref(case, with_state):
+    """The kernel's output and final state, and its cotangents of x, dt_a
+    (through the in-chunk cumsum), B, C and the initial state, match
+    `jax.vjp` through the chunked jnp path and through the float32
+    recurrence."""
+    chunk, dtype = case[6], case[7]
+    got = _ssd_value_and_grads(
+        lambda x, a, bp, cp, h0: ssd_scan(x, a, bp, cp, chunk=chunk, initial_state=h0,
+                                          interpret=True), case, with_state)
+    chunked = _ssd_value_and_grads(
+        lambda x, a, bp, cp, h0: ssd_chunked(x, a, bp, cp, chunk, initial_state=h0),
+        case, with_state)
+
+    def ref_scan(x, a, bp, cp, h0):   # float32 throughout: heads' dB, dC summed in fp32
+        f32 = [u.astype(jnp.float32) for u in (x, a, bp, cp)]
+        y, state = ssd_ref(*f32, initial_state=h0)
+        return y.astype(x.dtype), state
+
+    ref = _ssd_value_and_grads(ref_scan, case, with_state)
+    names = ("y", "state", "dx", "ddt_a", "dB", "dC", "dstate")
+    for name, g, c, r in zip(names, got, chunked, ref):
+        assert g.dtype == r.dtype, name
+        for want in (c, r):
+            np.testing.assert_allclose(np.asarray(g, np.float32), np.asarray(want, np.float32),
+                                       **_tol(dtype), err_msg=name)
+
+
 def test_ssd_kernel_initial_state_continuation():
     """Splitting a sequence and passing the carry state must equal one
     pass over the full sequence (the decode/prefill contract)."""
@@ -324,3 +369,46 @@ def test_ssd_property(s_chunks, h, g, p, n):
                                rtol=5e-3, atol=5e-3)
     np.testing.assert_allclose(np.asarray(h_k), np.asarray(h_r),
                                rtol=5e-3, atol=5e-3)
+
+
+def _granite_mamba_args(s: int):
+    cfg = get_config("granite-4.0-h-micro")
+    p = {name: jax.ShapeDtypeStruct(spec.shape, spec.dtype)
+         for name, spec in S.mamba_spec(cfg).items()}
+    return cfg, p, jax.ShapeDtypeStruct((1, s, cfg.d_model), jnp.bfloat16)
+
+
+def test_ssd_lowered_for_the_cpu_takes_the_jnp_path():
+    """Granite's training call fits the kernel, but the platform the step
+    is lowered for decides: on the CPU the jnp scan, counted `ssd.xla`."""
+    cfg, p, x = _granite_mamba_args(512)
+
+    def loss(p, x):
+        return S.mamba_block(p, cfg, x)[0].astype(jnp.float32).sum()
+
+    with trace.recording() as rec:
+        text = jax.jit(jax.grad(loss)).lower(p, x).as_text()
+    assert rec.counters.get("ssd.xla", 0) > 0
+    assert rec.counters.get("ssd.kernel", 0) == 0
+    assert "tpu_custom_call" not in text
+
+
+@pytest.mark.parametrize("case", ["train", "one_token", "chunk_64", "head_dim_32",
+                                  "state_32", "four_heads_a_group", "mesh"])
+def test_ssd_kernel_fits_only_what_it_tiles(case):
+    """What `mamba_block` hands the kernel where the lowering is for a
+    TPU: granite's training scan, and none of the calls that must keep the
+    jnp scan."""
+    s = 1 if case == "one_token" else 512
+    h, g = (8, 2) if case == "four_heads_a_group" else (64, 1)
+    p = 32 if case == "head_dim_32" else 64
+    n = 32 if case == "state_32" else 128
+    chunk = 64 if case == "chunk_64" else 256
+    x = jnp.zeros((1, s, h, p), jnp.bfloat16)
+    b_proj = jnp.zeros((1, s, g, n), jnp.bfloat16)
+    rules = None
+    if case == "mesh":   # two mesh slots, whatever devices the process has
+        devices = np.array(jax.devices()[:1] * 2).reshape(2, 1)
+        rules = ShardingRules(jax.sharding.Mesh(devices, ("data", "model")), TRAIN_RULES)
+    with use_rules(rules):
+        assert S._ssd_kernel_fits(x, b_proj, chunk) == (case == "train")

@@ -24,6 +24,7 @@ from repro.kernels import ops
 from repro.models import layers as L
 from repro.models import lm as LM
 from repro.models import make_model
+from repro.models import ssd as S
 from repro.sharding.rules import TRAIN_RULES, ShardingRules, use_rules
 from repro.train import AdamWConfig, StepConfig, build_train_step, init_train_state
 from repro.utils import trace
@@ -108,10 +109,18 @@ def smollm():
     return make_model(get_config("smollm-135m"))
 
 
+def _granite_10l():
+    """The granite cell's configuration: one period (10 of 40 layers) and
+    an eighth of the vocabulary."""
+    return dataclasses.replace(get_config("granite-4.0-h-micro"), num_layers=10,
+                               vocab_size=12544)
+
+
 def test_train_step_compiles_for_one_chip(one_chip, smollm):
     compiled, counters = _compile_train_step(one_chip, smollm, TRAIN_BATCH, TRAIN_SEQ)
     _assert_fits(compiled)
     _assert_attention_fused(compiled, counters, TRAIN_SEQ)
+    assert not [k for k in counters if k.startswith("ssd.")], counters
 
 
 def test_olmo_train_step_compiles_for_one_chip(one_chip):
@@ -121,6 +130,7 @@ def test_olmo_train_step_compiles_for_one_chip(one_chip):
     compiled, counters = _compile_train_step(one_chip, make_model(cfg), 4, 2048)
     _assert_fits(compiled)
     _assert_attention_fused(compiled, counters, 2048)
+    assert not [k for k in counters if k.startswith("ssd.")], counters
 
 
 def test_prefill_and_decode_compile_for_one_chip(one_chip, smollm):
@@ -147,14 +157,15 @@ def test_granite_train_step_fills_one_chip(one_chip):
     """The granite cell's step: one period (10 of 40 layers) and an eighth
     of the vocabulary at 1 x 8192. Its fp32 state and AdamW moments alone
     are 9.27 GB; per-block remat must keep the rest under 16 GB, and the
-    cell must use at least three quarters of the chip."""
-    cfg = dataclasses.replace(get_config("granite-4.0-h-micro"), num_layers=10,
-                              vocab_size=12544)
-    compiled, counters = _compile_train_step(one_chip, make_model(cfg), 1, 8192)
+    cell must use at least three quarters of the chip. Every Mamba-2
+    mixer's scan takes the fused kernel."""
+    compiled, counters = _compile_train_step(one_chip, make_model(_granite_10l()), 1, 8192)
     _assert_fits(compiled)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes >= 0.75 * HBM_BYTES
     _assert_attention_fused(compiled, counters, 8192)
+    assert counters.get("ssd.kernel", 0) > 0, counters
+    assert counters.get("ssd.xla", 0) == 0, counters
 
 
 @pytest.mark.parametrize("case", ["train", "cross", "seq_not_whole_blocks", "mesh"])
@@ -185,6 +196,35 @@ def test_attention_path_lowered_for_the_chip(topo, one_chip, case):
     assert ("tpu_custom_call" in text) == took_kernel
 
 
+@pytest.mark.parametrize("case", ["train", "decode", "mesh"])
+def test_ssd_path_lowered_for_the_chip(topo, one_chip, case):
+    """Granite's Mamba-2 mixer lowered for the chip under `jax.grad`: the
+    cache-less call at 1 x 8192 takes the fused scan; a decode step and a
+    mesh of four chips keep the jnp scan."""
+    cfg = _granite_10l()
+    batch, s = {"train": (1, 8192), "decode": (1, 1), "mesh": (4, 2048)}[case]
+    sharding, rules = one_chip, None
+    if case == "mesh":
+        mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+        sharding, rules = NamedSharding(mesh, PartitionSpec()), ShardingRules(mesh, TRAIN_RULES)
+    p = {name: jax.ShapeDtypeStruct(spec.shape, spec.dtype, sharding=sharding)
+         for name, spec in S.mamba_spec(cfg).items()}
+    x = jax.ShapeDtypeStruct((batch, s, cfg.d_model), jnp.bfloat16, sharding=sharding)
+    cache = (_on(sharding, jax.eval_shape(lambda: S.make_mamba_cache(cfg, batch)))
+             if case == "decode" else None)
+
+    def loss(p, x, cache):
+        y, _ = S.mamba_block(p, cfg, x, cache=cache)
+        return y.astype(jnp.float32).sum()
+
+    with use_rules(rules), trace.recording() as rec:
+        text = jax.jit(jax.grad(loss)).lower(p, x, cache).as_text()
+    took_kernel = case == "train"
+    assert rec.counters.get("ssd.kernel", 0) == (1 if took_kernel else 0), rec.counters
+    assert rec.counters.get("ssd.xla", 0) == (0 if took_kernel else 1), rec.counters
+    assert ("tpu_custom_call" in text) == took_kernel
+
+
 def test_flash_attention_lowers_to_a_kernel(one_chip):
     cfg = get_config("smollm-135m")
     q = jax.ShapeDtypeStruct((TRAIN_BATCH, cfg.num_heads, TRAIN_SEQ, cfg.head_dim),
@@ -205,10 +245,17 @@ def test_ssd_scan_lowers_to_a_kernel(one_chip):
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    compiled = ops.ssd_scan.lower(
-        arg((b, s, h, p), jnp.bfloat16), arg((b, s, h), jnp.float32),
-        arg((b, s, g, n), jnp.bfloat16), arg((b, s, g, n), jnp.bfloat16),
-        chunk=cfg.ssm_chunk,
-    ).compile()
+    args = (arg((b, s, h, p), jnp.bfloat16), arg((b, s, h), jnp.float32),
+            arg((b, s, g, n), jnp.bfloat16), arg((b, s, g, n), jnp.bfloat16))
+    compiled = ops.ssd_scan.lower(*args, chunk=cfg.ssm_chunk).compile()
     assert "tpu_custom_call" in compiled.as_text()
     _assert_fits(compiled)
+
+    def loss(x, dt_a, b_proj, c_proj):
+        y, state = ops.ssd_scan(x, dt_a, b_proj, c_proj, chunk=cfg.ssm_chunk)
+        return y.astype(jnp.float32).sum() + state.sum()
+
+    grads = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(*args).compile()
+    text = grads.as_text()
+    assert "ssd_scan_fwd" in text and "ssd_scan_bwd" in text
+    _assert_fits(grads)
